@@ -40,7 +40,7 @@ from repro.distributed import sharding
 
 fact = F.tt((12, 8, 8), (8, 8, 12), 8)          # ATIS-TT (Table II)
 tokens = 128
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = sharding.make_mesh((8, 1), ("data", "model"))
 mspec = sharding.mesh_spec(mesh, {"b": ("data",)})
 
 rows = []
@@ -129,6 +129,9 @@ print("ROWS=" + json.dumps(rows))
 def run(print_fn=print) -> list[dict]:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # The forced devices are host devices: a CPU run, which must never
+    # contend for a chip the parent process may hold.
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))
     out = subprocess.run([sys.executable, "-c", _WORKER],

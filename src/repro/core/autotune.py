@@ -72,7 +72,7 @@ from repro.core.plan_compiler import (
 )
 from repro.core.tnetwork import ContractionPlan
 from repro.kernels.fused_contraction import (
-    CHAIN_VMEM_BUDGET_BYTES, INTERPRET, chain_n_pallas, chain_n_vmem_elems,
+    CHAIN_VMEM_BUDGET_BYTES, INTERPRET, chain_n_pallas, chain_vmem_bytes,
     chain_plan, matmul_pallas,
 )
 
@@ -441,8 +441,8 @@ class Tuner:
             cands = [TileConfig(block_m=a, block_n=b) for a, b in raw]
             # chain tiles must respect the kernel's VMEM budget check
             cands = [t for t in cands
-                     if chain_n_vmem_elems(m0, links, t.block_m, t.block_n)
-                     * 4 < CHAIN_VMEM_BUDGET_BYTES]
+                     if chain_vmem_bytes(m0, links, t.block_m, t.block_n)
+                     <= CHAIN_VMEM_BUDGET_BYTES]
             eff = lambda t: (min(t.block_m, m), min(t.block_n, n))  # noqa: E731
         cands = _dedupe_tile_candidates(cands, eff)
         if len(cands) > self.max_configs:

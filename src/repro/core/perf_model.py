@@ -77,6 +77,18 @@ class HardwareModel:
 
 TPU_V5E = HardwareModel()
 
+#: Planning models by the ``device_kind`` JAX reports for the chip.
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device_kind: str) -> HardwareModel:
+    """The planning model of a chip; an unlisted chip is an error, never
+    planned with another chip's peaks."""
+    if device_kind not in DEVICE_KINDS:
+        raise ValueError(f"no planning model for device kind "
+                         f"{device_kind!r} (known: {sorted(DEVICE_KINDS)})")
+    return DEVICE_KINDS[device_kind]
+
 
 def apply_policy(hw: HardwareModel, policy) -> HardwareModel:
     """Retarget a hardware model to a quantization policy's storage width.

@@ -51,7 +51,7 @@ from repro.core.contraction import _einsum_spec, _einsum_step
 from repro.core.tnetwork import AxisId, ContractionPlan, ContractionStep
 from repro.kernels.fused_contraction import (
     CHAIN_VMEM_BUDGET_BYTES, ChainLoweringError, chain_n_pallas,
-    chain_n_vmem_elems, chain_plan, matmul_pallas,
+    chain_plan, chain_vmem_bytes, matmul_pallas,
 )
 
 _log = tm.get_logger("plan_compiler")
@@ -320,10 +320,10 @@ def _chain_shapes(run: Sequence[GemmOp]) -> tuple[tuple[int, int], ...]:
 
 def _chain_fits(run: Sequence[GemmOp], vmem_budget: int) -> bool:
     try:
-        elems = chain_n_vmem_elems(run[0].mat.m, _chain_shapes(run))
+        return chain_vmem_bytes(run[0].mat.m,
+                                _chain_shapes(run)) <= vmem_budget
     except ChainLoweringError:
         return False
-    return elems * 4 < vmem_budget
 
 
 def _build_chain(run: Sequence[GemmOp]) -> ChainOp:

@@ -96,6 +96,8 @@ def healthy_device_mesh(min_devices: int = 1):
     axis if the device count still factors, else collapses to pure DP."""
     import jax
 
+    from repro.distributed.sharding import make_mesh
+
     n = len(jax.devices())
     assert n >= min_devices, f"only {n} devices visible"
     model = 1
@@ -103,4 +105,4 @@ def healthy_device_mesh(min_devices: int = 1):
         if n % cand == 0:
             model = cand
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

@@ -37,7 +37,7 @@ from typing import Any, Mapping, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import perf_model
 from repro.core.tnetwork import AxisId, ContractionPlan, TensorNetwork
@@ -55,6 +55,18 @@ DEFAULT_RULES: dict[str, Any] = {
     "vocab": "model",
     "embed": None,
 }
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Sequence[Any] | None = None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the compiler propagates
+    shardings between the ``with_sharding_constraint`` hints that
+    :func:`make_sharder` places.  jax's own default is ``Explicit`` axes,
+    which that constraint rejects.  Every mesh the trainer, the server and
+    the elastic restart build comes from here."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def _axes_in(mesh: Mesh, spec) -> tuple[str, ...]:
@@ -93,7 +105,24 @@ def make_sharder(mesh: Mesh | None, rules: dict[str, Any] | None = None):
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, P(*parts)))
 
+    shard.mesh = mesh      # read by batch_parallel's callers (attention)
     return shard
+
+
+def batch_parallel(fn, mesh: Mesh | None, batch: int):
+    """``fn`` run once per batch shard of ``mesh``: a ``shard_map`` that
+    splits dim 0 of every operand and result over the mesh's batch axes.
+
+    The compiler cannot partition a Pallas kernel, so a kernel that works
+    row by row on the batch (flash attention) reaches a multi-device mesh
+    through this.  Where the batch axes do not divide ``batch``, every
+    device runs the whole batch.  One device or no mesh returns ``fn``."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    axes = _axes_in(mesh, DEFAULT_RULES["batch"])
+    spec = P(axes) if axes and batch % _mesh_size(mesh, axes) == 0 else P()
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)
 
 
 # ---------------------------------------------------------------------------

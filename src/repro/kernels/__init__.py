@@ -4,7 +4,6 @@ docs/MEGAKERNEL.md).
 MXU-tiled GEMMs with fused operand transpose, N-step on-chip contraction
 chains (``chain_n_pallas``), and the quantized (fp8/int8, scaled-epilogue)
 variants — reached through :mod:`repro.core.plan_compiler`, never called
-directly by model code.  :mod:`~repro.kernels.compat` shims the Pallas
-API surface across supported jax versions; interpret mode keeps every
-kernel CPU-runnable.
+directly by model code.  Interpret mode runs every kernel on the CPU for
+the tests; on a TPU they compile through Mosaic.
 """

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 from repro.kernels.fused_contraction import INTERPRET
 
 
@@ -71,7 +69,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         o_ref[0] = (acc_ref[...]
                     / jnp.maximum(l_ref[...], 1e-30)[:, None]
                     ).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
+        lse_ref[0] = (m_ref[...]
+                      + jnp.log(jnp.maximum(l_ref[...], 1e-30)))[None, :]
 
 
 def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -116,23 +115,25 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=[
             pl.BlockSpec((1, q_chunk, D), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, q_chunk), lambda h, i, j: (h, i)),
+            # lse is [B*H, 1, Tq]: q positions on the lanes, so the
+            # block's last two dims (1, q_chunk) tile the TPU layout.
+            pl.BlockSpec((1, 1, q_chunk), lambda h, i, j: (h, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Tq), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, Tq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((q_chunk,), jnp.float32),      # running max
             pltpu.VMEM((q_chunk,), jnp.float32),      # running denom
             pltpu.VMEM((q_chunk, D), jnp.float32),    # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
     out, lse = out if isinstance(out, (tuple, list)) else (out, None)
     out = out.reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
-    # [B*H, Tq] -> [B, Tq, KV, G]   (H is KV-major: h = kv * G + g)
+    # [B*H, 1, Tq] -> [B, Tq, KV, G]   (H is KV-major: h = kv * G + g)
     lse = lse.reshape(B, KV, G, Tq).transpose(0, 3, 1, 2)
     return out, lse

@@ -46,14 +46,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 INTERPRET = jax.default_backend() != "tpu"
 
-# Conservative VMEM budget for the chain kernel's resident operand set; the
-# plan compiler (repro.core.plan_compiler) consults the same numbers when
-# deciding whether a run of adjacent steps may fuse.
-CHAIN_VMEM_BUDGET_BYTES = 100 * 2 ** 20
+# VMEM the chain kernel is compiled under (``vmem_limit_bytes``) and the
+# budget its footprint model (:func:`chain_vmem_bytes`) is checked against:
+# half of a v5e core's 128 MiB, the residency ``perf_model.TPU_V5E``
+# assumes.  The plan compiler (repro.core.plan_compiler) consults the same
+# model when deciding whether a run of adjacent steps may fuse, so a chain
+# it emits is one the TPU compiler accepts.
+CHAIN_VMEM_BUDGET_BYTES = 64 * 2 ** 20
 
 
 class ChainLoweringError(ValueError):
@@ -71,15 +72,6 @@ class ChainLoweringError(ValueError):
 def _require(cond: bool, msg: str) -> None:
     if not cond:
         raise ChainLoweringError(msg)
-
-
-def chain_vmem_elems(m: int, k: int, h: int, n: int,
-                     block_m: int = 128, block_n: int = 128) -> int:
-    """f32 elements resident in VMEM for one 2-step chain grid cell
-    (historical single-scratch accounting; :func:`chain_n_vmem_elems` is
-    the N-step double-buffered generalisation)."""
-    bm, bn = min(block_m, m), min(block_n, n)
-    return bm * k + k * h + h * bn + bm * h + bm * bn
 
 
 def chain_plan(m0: int, shapes) -> tuple[tuple[int, ...], tuple[int, ...]]:
@@ -114,25 +106,47 @@ def chain_plan(m0: int, shapes) -> tuple[tuple[int, ...], tuple[int, ...]]:
     return tuple(rows), tuple(regroups)
 
 
-def chain_n_vmem_elems(m0: int, shapes,
-                       block_m: int = 128, block_n: int = 128) -> int:
-    """f32 elements resident in VMEM for one ``chain_n_pallas`` grid cell.
+def _tile_bytes(rows: int, cols: int) -> int:
+    """VMEM bytes of a ``[rows, cols]`` block: padded to the (8, 128) f32
+    tile at 4 bytes per element, which bounds the bf16 (16, 128) and
+    8-bit (32, 128) tilings of the same block from above."""
+    return -(-rows // 8) * 8 * (-(-cols // 128) * 128) * 4
+
+
+def chain_vmem_bytes(m0: int, shapes,
+                     block_m: int = 128, block_n: int = 128) -> int:
+    """Upper bound on the VMEM one ``chain_n_pallas`` grid cell occupies.
 
     ``shapes`` is the per-link ``(k_i, n_i)`` weight shape tuple (see
-    :func:`chain_plan`); ``m0`` the first link's row count.  Interior
-    weights are resident whole, the last weight per column block, plus the
-    x row block, the two ping-pong intermediate scratch buffers (sized for
-    the widest per-final-row intermediate) and the output tile.
+    :func:`chain_plan`); ``m0`` the first link's row count.  Counted for
+    any operand dtype: every pipelined block twice (Pallas double-buffers
+    inputs and outputs, the resident interior weights included), the two
+    ping-pong intermediate buffers, the scale blocks of the quantized
+    kernel, and the widest link's values (the strided row pieces of a
+    regroup, lhs, weight and two f32 accumulators) that the compiler
+    keeps on its VMEM stack.
     """
     shapes = tuple(shapes)
     rows, _ = chain_plan(m0, shapes)
     m_final, n_last = rows[-1], shapes[-1][1]
     bm, bn = min(block_m, m_final), min(block_n, n_last)
-    mults = [r // m_final for r in rows]         # R_i: rows per final row
-    interior_w = sum(k * n for k, n in shapes[:-1])
-    inter_cols = [mults[i] * shapes[i][1] for i in range(len(shapes) - 1)]
-    return (bm * mults[0] * shapes[0][0] + interior_w
-            + shapes[-1][0] * bn + 2 * bm * max(inter_cols) + bm * bn)
+    link_rows = [bm * (r // m_final) for r in rows]
+    pipelined = (_tile_bytes(link_rows[0], shapes[0][0])
+                 + sum(_tile_bytes(k, n) for k, n in shapes[:-1])
+                 + _tile_bytes(shapes[-1][0], bn)
+                 + _tile_bytes(link_rows[0], 1)
+                 + (len(shapes) - 2) * _tile_bytes(1, 1)
+                 + _tile_bytes(1, bn)
+                 + _tile_bytes(bm, bn))
+    scratch = 2 * _tile_bytes(max(link_rows[:-1]),
+                              max(n for _, n in shapes[:-1]))
+    values = []
+    for i, (r, (k, n)) in enumerate(zip(link_rows, shapes)):
+        g = k // shapes[i - 1][1] if i else 1
+        pieces = g * _tile_bytes(r, shapes[i - 1][1]) if g > 1 else 0
+        values.append(pieces + _tile_bytes(r, k) + _tile_bytes(k, n)
+                      + 2 * _tile_bytes(r, n))
+    return 2 * pipelined + scratch + max(values)
 
 
 # ---------------------------------------------------------------------------
@@ -250,7 +264,7 @@ def matmul_pallas(x: jax.Array, w: jax.Array, *, transpose_rhs: bool = False,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w, *scale_ops)
@@ -273,14 +287,17 @@ def _chain_n_kernel(*refs, h_dtype, n_w: int, bm: int,
     VMEM double-buffering half of the pipeline (operand-tile prefetch
     across grid cells is Pallas's BlockSpec pipeline).
 
-    Link ``i`` computes on ``bm * mults[i]`` rows; where ``mults`` steps
-    down, the intermediate is re-read regrouped (``[r, n] -> [r/g,
-    g*n]``) — a contiguous row-major reshape performed on the VMEM value,
-    never in HBM.  Intermediates are stored per-final-row as ``[bm,
-    mults[i] * n_i]`` so both regrouped and fixed-M links read the same
-    layout.  Quantized links multiply each dot by that link's folded
-    dequantization scale before the downcast, so every resident
-    intermediate holds *real* values.
+    Link ``i`` computes on ``bm * mults[i]`` rows, and its intermediate is
+    stored row-major as ``[bm * mults[i], n_i]``.  Where the next link
+    regroups (``[r, n] -> [r/g, g*n]``, ``g = k_{i+1} / n_i``), it never
+    reshapes the value: row ``f`` of the regrouped lhs is rows ``f*g ..
+    f*g+g-1`` side by side, so the link reads the rows ``j, j+g, j+2g,
+    ...`` for each ``j < g`` (strided VMEM reads) and concatenates them
+    along the lanes.  The TPU's layout pass refuses the lane-changing
+    reshape and accepts this; the dot that follows is the same single
+    dot over ``K = g*n`` the reshape fed.  Quantized links multiply
+    each dot by that link's folded dequantization scale before the
+    downcast, so every resident intermediate holds *real* values.
     """
     x_ref = refs[0]
     w_refs = refs[1:1 + n_w]
@@ -293,25 +310,33 @@ def _chain_n_kernel(*refs, h_dtype, n_w: int, bm: int,
     t_refs = (t0_ref, t1_ref)
     for i in range(n_w):
         k_i, n_i = shapes[i]
+        rows = bm * mults[i]
         if i == 0:
-            lhs = x_ref[...]                      # (bm * mults[0], k_1)
+            lhs, w = x_ref[...], w_refs[0][...]
             if quant:
-                lhs = lhs.astype(jnp.float32)
+                lhs, w = lhs.astype(jnp.float32), w.astype(jnp.float32)
+            acc = jnp.dot(lhs, w, preferred_element_type=jnp.float32)
         else:
-            cols = mults[i - 1] * shapes[i - 1][1]
-            flat = t_refs[(i - 1) % 2][:, :cols].astype(h_dtype)
-            lhs = flat.reshape(bm * mults[i], k_i)   # regroup in VMEM
-        w = w_refs[i][...]
-        if quant:
-            w = w.astype(jnp.float32 if i == 0 else h_dtype)
-        acc = jnp.dot(lhs, w, preferred_element_type=jnp.float32)
+            n_prev = shapes[i - 1][1]
+            g = k_i // n_prev
+            prev = t_refs[(i - 1) % 2]
+            if g == 1:
+                lhs = prev[:rows, :n_prev]
+            else:                                # regroup in VMEM
+                lhs = jnp.concatenate(
+                    [prev[pl.ds(j, rows, stride=g), :n_prev]
+                     for j in range(g)], axis=1)
+            w = w_refs[i][...]
+            if quant:
+                w = w.astype(h_dtype)
+            acc = jnp.dot(lhs.astype(h_dtype), w,
+                          preferred_element_type=jnp.float32)
         if quant:
             acc = acc * s_refs[i][...]
         if i == n_w - 1:
             o_ref[...] = acc.astype(o_ref.dtype)  # (bm, bn)
         else:
-            t_refs[i % 2][:, :mults[i] * n_i] = acc.reshape(
-                bm, mults[i] * n_i)
+            t_refs[i % 2][:rows, :n_i] = acc
 
 
 def chain_n_pallas(x: jax.Array, weights, *,
@@ -328,8 +353,9 @@ def chain_n_pallas(x: jax.Array, weights, *,
     mode axis per step" structure becomes one on-chip chain.  The output
     is ``[m0 / prod(g), n_last]``.  Interior boundary operands must fit in
     VMEM alongside the tiles (true for TNN cores, where each boundary is a
-    product of a few factor/rank dims); the wrapper enforces a
-    conservative budget via :class:`ChainLoweringError`.
+    product of a few factor/rank dims); the wrapper raises
+    :class:`ChainLoweringError` when :func:`chain_vmem_bytes` exceeds the
+    VMEM limit the kernel is compiled under.
 
     ``scales`` switches to the quantized kernel: operands hold fp8/int8
     values and ``scales`` carries one folded dequantization factor per
@@ -359,9 +385,9 @@ def chain_n_pallas(x: jax.Array, weights, *,
     interpret = INTERPRET if interpret is None else interpret
 
     bm, bn = min(block_m, m_final), min(block_n, n)
-    vmem_elems = chain_n_vmem_elems(m0, shapes, block_m, block_n)
-    _require(vmem_elems * 4 < CHAIN_VMEM_BUDGET_BYTES,
-             f"chain operands exceed VMEM budget: {vmem_elems * 4} bytes")
+    vmem_bytes = chain_vmem_bytes(m0, shapes, block_m, block_n)
+    _require(vmem_bytes <= CHAIN_VMEM_BUDGET_BYTES,
+             f"chain operands exceed VMEM budget: {vmem_bytes} bytes")
     mults = tuple(r // m_final for r in rows)    # R_i: rows per final row
 
     mp, np_ = (-m_final % bm), (-n % bn)
@@ -415,8 +441,8 @@ def chain_n_pallas(x: jax.Array, weights, *,
     w_specs = [pl.BlockSpec(shapes[i], lambda i_, j_: (0, 0))
                for i in range(n_w - 1)]
     w_specs.append(pl.BlockSpec((shapes[-1][0], bn), lambda i, j: (0, j)))
-    inter_cols = [mults[i] * shapes[i][1] for i in range(n_w - 1)]
-    max_mid = max(inter_cols)
+    mid_rows = bm * max(mults[:-1])
+    mid_cols = max(n for _, n in shapes[:-1])
 
     out = pl.pallas_call(
         kernel,
@@ -429,10 +455,11 @@ def chain_n_pallas(x: jax.Array, weights, *,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, max_mid), jnp.float32),
-                        pltpu.VMEM((bm, max_mid), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        scratch_shapes=[pltpu.VMEM((mid_rows, mid_cols), jnp.float32),
+                        pltpu.VMEM((mid_rows, mid_cols), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=CHAIN_VMEM_BUDGET_BYTES),
         interpret=interpret,
     )(x, *weights, *scale_ops)
     return out[:m_final, :n]

@@ -21,8 +21,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.fused_contraction import INTERPRET
 from repro.precision.policy import QuantPolicy
 
@@ -69,7 +69,7 @@ def quantize_pallas(x: jax.Array, scale: jax.Array, policy: QuantPolicy, *,
                   pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r + rp, c), policy.operand_dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, scale)
     return q[:r]
@@ -94,7 +94,7 @@ def dequantize_pallas(q: jax.Array, scale: jax.Array, *,
                   pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r + rp, c), out_dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(q, scale)
     return out[:r]

@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 from repro.kernels.fused_contraction import INTERPRET
 
 
@@ -48,8 +46,14 @@ def _scan_kernel(q_ref, k_ref, v_ref, ld_ref, u_ref, o_ref, sout_ref,
     v = v_ref[0].astype(jnp.float32)           # [C, dv]
     ld = ld_ref[0].astype(jnp.float32)         # [C, dk] log-decay (<= 0)
     c = q.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
 
-    lc = jnp.cumsum(ld, axis=0)                # inclusive log cumprod
+    # Inclusive log cumprod as a lower-triangular matmul: the TPU lowering
+    # has no cumsum.  HIGHEST keeps the f32 sums exact to f32 rounding.
+    lc = jnp.dot((row >= col).astype(jnp.float32), ld,
+                 preferred_element_type=jnp.float32,
+                 precision=jax.lax.Precision.HIGHEST)
     if mode == "ssd":
         ex = lc                                # output sees decayed state
     else:                                      # rwkv6: output sees S_{t-1}
@@ -58,15 +62,13 @@ def _scan_kernel(q_ref, k_ref, v_ref, ld_ref, u_ref, o_ref, sout_ref,
     q_t = q * jnp.exp(ex)                      # [C, dk]
     k_t = k * jnp.exp(-lc)                     # [C, dk]
     att = jnp.dot(q_t, k_t.T, preferred_element_type=jnp.float32)  # [C, C]
-    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
     if mode == "ssd":
         att = jnp.where(row >= col, att, 0.0)
     else:
         att = jnp.where(row > col, att, 0.0)
         u = u_ref[0].astype(jnp.float32)       # [1, dk] bonus
-        diag = jnp.sum(q * u * k, axis=-1)     # [C]
-        att += jnp.diag(diag)
+        diag = jnp.sum(q * u * k, axis=-1, keepdims=True)     # [C, 1]
+        att += jnp.where(row == col, diag, 0.0)
 
     inter = jnp.dot(q_t, state_ref[...],
                     preferred_element_type=jnp.float32)            # [C, dv]
@@ -74,8 +76,11 @@ def _scan_kernel(q_ref, k_ref, v_ref, ld_ref, u_ref, o_ref, sout_ref,
                 + inter).astype(o_ref.dtype)
 
     # State update: S_out = diag(exp(lc[-1])) S_in + (k*exp(lc[-1]-lc))^T v
-    k_s = k * jnp.exp(lc[-1:] - lc)            # [C, dk]
-    state_ref[...] = (state_ref[...] * jnp.exp(lc[-1])[:, None]
+    # lc[-1] is the chunk's whole log-decay, summed as a row for k_s and
+    # as a column (over ld^T) for the state rows.
+    k_s = k * jnp.exp(jnp.sum(ld, axis=0, keepdims=True) - lc)   # [C, dk]
+    decay = jnp.exp(jnp.sum(ld.T, axis=1, keepdims=True))        # [dk, 1]
+    state_ref[...] = (state_ref[...] * decay
                       + jnp.dot(k_s.T, v, preferred_element_type=jnp.float32))
 
     @pl.when(pl.program_id(1) == num_chunks - 1)
@@ -126,7 +131,7 @@ def linear_scan_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, log_decay, u3)

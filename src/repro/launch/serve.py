@@ -25,12 +25,68 @@ from repro import telemetry as tm
 from repro.configs import base as cfgbase
 from repro.distributed import sharding
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.memory.planner import format_bytes
 from repro.serving import profiles as profiles_lib
 from repro.serving.engine import Request, ServeEngine
 
 _log = tm.get_logger("serve")
+
+
+def serve(arch_id: str, *, smoke: bool, tnn: bool, requests: int = 8,
+          batch: int = 4, prompt_len: int = 16, max_new: int = 16,
+          kv_dtype: str = "bf16", memory_budget=None,
+          prefill_chunk: int = 32,
+          max_prefill_tokens: int | None = None) -> dict:
+    """Serve ``requests`` random prompts through the continuous-batching
+    engine; returns the completed requests, the engine, the warmup
+    (compile) seconds and the seconds of every tick."""
+    arch = cfgbase.get(arch_id)
+    tnn_cfg = arch.tnn_default if tnn else None
+    model, cfg = steps_lib.build_model(arch, tnn=tnn_cfg, smoke=smoke)
+    mesh = make_host_mesh()
+    shard = sharding.make_sharder(mesh)
+    params = model.init(jax.random.key(0))
+
+    # Phase-specialized planning at server start: prefill and decode get
+    # their own CSSE/autotune cache entries (phase-tagged signatures).
+    prof = profiles_lib.build_profiles(
+        cfg, batch_size=batch, prefill_chunk=prefill_chunk)
+    if prof:
+        # raw print (no [serve] prefix historically): profile_summary is
+        # its own multi-line block
+        print(profiles_lib.profile_summary(prof))
+
+    engine = ServeEngine(
+        model, params, batch_size=batch,
+        max_len=prompt_len + max_new + 8,
+        shard=shard,
+        prefill_chunk=prefill_chunk,
+        max_prefill_tokens=max_prefill_tokens,
+        kv_policy=kv_dtype,
+        memory_budget=memory_budget)
+    _log.info(f"slot KV: {format_bytes(engine.slot_cost['total'])} "
+              f"({kv_dtype}), capacity {engine.capacity}/"
+              f"{batch} slots")
+    rng = np.random.default_rng(0)
+    for rid in range(requests):
+        engine.submit(Request(
+            rid=rid,
+            prompt=rng.integers(0, cfg.vocab, size=prompt_len,
+                                dtype=np.int32),
+            max_new_tokens=max_new,
+            temperature=0.0 if rid % 2 == 0 else 0.8))
+    t0 = time.time()
+    engine.warmup()
+    warmup_s = time.time() - t0
+    tick_s = []
+    while engine.busy:       # engine.run(), one timed tick at a time
+        t = time.time()
+        engine.step()
+        tick_s.append(time.time() - t)
+    return {"done": engine.completed, "engine": engine,
+            "warmup_s": warmup_s, "tick_s": tick_s}
 
 
 def main() -> None:
@@ -58,49 +114,20 @@ def main() -> None:
                          "tick spans, occupancy samples — "
                          "docs/OBSERVABILITY.md)")
     args = ap.parse_args()
+    enable_compile_cache()
     owns_trace = bool(args.serve_trace) and not tm.enabled()
     if owns_trace:
         tm.configure(args.serve_trace)
 
-    arch = cfgbase.get(args.arch)
-    tnn_cfg = arch.tnn_default if args.tnn else None
-    model, cfg = steps_lib.build_model(arch, tnn=tnn_cfg, smoke=args.smoke)
-    mesh = make_host_mesh()
-    shard = sharding.make_sharder(mesh)
-    params = model.init(jax.random.key(0))
-
-    # Phase-specialized planning at server start: prefill and decode get
-    # their own CSSE/autotune cache entries (phase-tagged signatures).
-    prof = profiles_lib.build_profiles(
-        cfg, batch_size=args.batch, prefill_chunk=args.serve_prefill_chunk)
-    if prof:
-        # raw print (no [serve] prefix historically): profile_summary is
-        # its own multi-line block
-        print(profiles_lib.profile_summary(prof))
-
-    engine = ServeEngine(
-        model, params, batch_size=args.batch,
-        max_len=args.prompt_len + args.max_new + 8,
-        shard=shard,
-        prefill_chunk=args.serve_prefill_chunk,
-        max_prefill_tokens=args.serve_max_prefill_tokens,
-        kv_policy=args.serve_kv_dtype,
-        memory_budget=args.serve_memory_budget)
-    _log.info(f"slot KV: {format_bytes(engine.slot_cost['total'])} "
-              f"({args.serve_kv_dtype}), capacity {engine.capacity}/"
-              f"{args.batch} slots")
-    rng = np.random.default_rng(0)
-    for rid in range(args.requests):
-        engine.submit(Request(
-            rid=rid,
-            prompt=rng.integers(0, cfg.vocab, size=args.prompt_len,
-                                dtype=np.int32),
-            max_new_tokens=args.max_new,
-            temperature=0.0 if rid % 2 == 0 else 0.8))
-    engine.warmup()
-    t0 = time.time()
-    done = engine.run()
-    dt = time.time() - t0
+    out = serve(args.arch, smoke=args.smoke, tnn=args.tnn,
+                requests=args.requests, batch=args.batch,
+                prompt_len=args.prompt_len, max_new=args.max_new,
+                kv_dtype=args.serve_kv_dtype,
+                memory_budget=args.serve_memory_budget,
+                prefill_chunk=args.serve_prefill_chunk,
+                max_prefill_tokens=args.serve_max_prefill_tokens)
+    done, engine = out["done"], out["engine"]
+    dt = sum(out["tick_s"])
     total_new = sum(len(r.out_tokens) for r in done)
     _log.info(f"{len(done)} requests, {total_new} tokens "
               f"in {dt:.2f}s ({total_new/dt:.1f} tok/s), "

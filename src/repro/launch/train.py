@@ -30,6 +30,7 @@ from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.distributed import fault_tolerance as ft
 from repro.distributed import sharding
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.optim.adamw import AdamW
 
@@ -49,7 +50,12 @@ def train(arch_id: str, *, smoke: bool, tnn: bool, steps: int,
           tnn_search: str = "per-axis",
           tnn_pipeline: int | None = None,
           loss_scale: float = 1.0,
-          trace_path: str | None = None) -> dict:
+          trace_path: str | None = None,
+          mesh=None) -> dict:
+    """Train ``arch_id`` for ``steps`` steps; returns the loss history,
+    per-step seconds (step 0 includes compilation) and the final state.
+    ``mesh`` overrides the device mesh (default: every local device on
+    the ``data`` axis, or the production mesh)."""
     # --tnn-trace: enable the telemetry tracer for this run (unless the
     # caller — or REPRO_TRACE — already did, in which case the run joins
     # the existing trace and does not own finalization).
@@ -57,7 +63,9 @@ def train(arch_id: str, *, smoke: bool, tnn: bool, steps: int,
     if owns_trace:
         tm.configure(trace_path)
     arch = cfgbase.get(arch_id)
-    mesh = (make_production_mesh() if production_mesh else make_host_mesh())
+    if mesh is None:
+        mesh = (make_production_mesh() if production_mesh
+                else make_host_mesh())
     tnn_cfg = arch.tnn_default if tnn else None
     if tnn_cfg is not None and tnn_backend is not None:
         tnn_cfg = dataclasses.replace(tnn_cfg, backend=tnn_backend)
@@ -216,7 +224,7 @@ def train(arch_id: str, *, smoke: bool, tnn: bool, steps: int,
         _log.info(f"resumed from step {start}")
 
     watchdog = ft.StepWatchdog()
-    history = []
+    history, step_s = [], []
     t_start = time.time()
     for step in range(start, steps):
         # Per-step phase breakdown: one train.step span with data-load
@@ -232,6 +240,7 @@ def train(arch_id: str, *, smoke: bool, tnn: bool, steps: int,
             dur = time.time() - t0
         watchdog.observe(step, dur)
         history.append(loss)
+        step_s.append(dur)
         if manager:
             with tm.span("train.checkpoint", step=step):
                 manager.maybe_save(step + 1, state)
@@ -248,7 +257,8 @@ def train(arch_id: str, *, smoke: bool, tnn: bool, steps: int,
     if owns_trace:
         tm.finalize()
     return {"losses": history, "final_loss": history[-1] if history else None,
-            "wall_s": wall, "stragglers": len(watchdog.straggler_events),
+            "wall_s": wall, "step_s": step_s,
+            "stragglers": len(watchdog.straggler_events),
             "peak_activation_bytes": (mem_probe.peak_bytes
                                       if mem_probe else None),
             "peak_source": mem_probe.source if mem_probe else None,
@@ -373,6 +383,7 @@ def main() -> None:
                  "partitions the tensorized layer stack)")
     if args.tnn_pipeline is not None and args.tnn_pipeline < 1:
         ap.error("--tnn-pipeline must be >= 1")
+    enable_compile_cache()
 
     def run(start_step: int) -> int:
         out = train(args.arch, smoke=args.smoke, tnn=args.tnn,

@@ -220,14 +220,16 @@ class Attention:
         k = shard(k, ("batch", "seq", "kv_heads", None))
         ctx = blockwise_attention(q, k, v, causal=self.causal,
                                   q_chunk=self.q_chunk,
-                                  kv_chunk=self.kv_chunk)
+                                  kv_chunk=self.kv_chunk,
+                                  mesh=getattr(shard, "mesh", None))
         return self._out(params, ctx)
 
     def prefill(self, params, x, positions, max_len: int, shard: Shard = no_shard):
         """Run full attention and return the populated KV cache."""
         q, k, v = self._qkv(params, x, positions)
         ctx = blockwise_attention(q, k, v, causal=self.causal,
-                                  q_chunk=self.q_chunk, kv_chunk=self.kv_chunk)
+                                  q_chunk=self.q_chunk, kv_chunk=self.kv_chunk,
+                                  mesh=getattr(shard, "mesh", None))
         B, T, KV, D = k.shape
         pad = max_len - T
         cache = KVCache(
@@ -335,7 +337,7 @@ class Attention:
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool, q_chunk: int, kv_chunk: int,
                         softmax_scale: float | None = None,
-                        flash_bwd: bool = True) -> jax.Array:
+                        flash_bwd: bool = True, mesh=None) -> jax.Array:
     """Memory-efficient attention with online softmax (flash-style).
 
     Never materialises the [T, T] score matrix: scans KV in chunks carrying
@@ -348,11 +350,14 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     of letting autodiff stash [nk, ..., q_chunk, kv_chunk] probability
     stacks in HBM — the flash-attention backward.  This was the dominant
     memory-roofline term of every training cell (EXPERIMENTS.md §Perf H1).
+
+    ``mesh`` (the activations' device mesh, if any) lets the Pallas
+    forward run per batch shard; the compiler cannot partition it.
     """
     if flash_bwd:
         scale = softmax_scale or 1.0 / math.sqrt(q.shape[-1])
         return _flash_attention(q, k, v, causal, min(q_chunk, q.shape[1]),
-                                min(kv_chunk, k.shape[1]), scale)
+                                min(kv_chunk, k.shape[1]), scale, mesh)
     return _blockwise_attention_fwd_only(
         q, k, v, causal=causal, q_chunk=q_chunk, kv_chunk=kv_chunk,
         softmax_scale=softmax_scale)[0]
@@ -434,32 +439,36 @@ def _blockwise_attention_fwd_only(q, k, v, *, causal, q_chunk, kv_chunk,
 _USE_PALLAS_FLASH = jax.default_backend() == "tpu"
 
 
-def _flash_forward_dispatch(q, k, v, causal, q_chunk, kv_chunk, scale):
+def _flash_forward_dispatch(q, k, v, causal, q_chunk, kv_chunk, scale,
+                            mesh):
     """On TPU the forward runs the Pallas kernel (probability tiles never
-    leave VMEM); elsewhere the jnp twin with identical semantics."""
+    leave VMEM), once per batch shard of ``mesh``; elsewhere the jnp twin
+    with identical semantics."""
     if _USE_PALLAS_FLASH:
+        from repro.distributed.sharding import batch_parallel
         from repro.kernels.flash_attention import flash_attention_fwd
-        return flash_attention_fwd(q, k, v, causal=causal, q_chunk=q_chunk,
-                                   kv_chunk=kv_chunk, softmax_scale=scale)
+        fwd = partial(flash_attention_fwd, causal=causal, q_chunk=q_chunk,
+                      kv_chunk=kv_chunk, softmax_scale=scale)
+        return batch_parallel(fwd, mesh, q.shape[0])(q, k, v)
     return _blockwise_attention_fwd_only(
         q, k, v, causal=causal, q_chunk=q_chunk, kv_chunk=kv_chunk,
         softmax_scale=scale)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_attention(q, k, v, causal: bool, q_chunk: int, kv_chunk: int,
-                     scale: float):
+                     scale: float, mesh):
     return _flash_forward_dispatch(q, k, v, causal, q_chunk, kv_chunk,
-                                   scale)[0]
+                                   scale, mesh)[0]
 
 
-def _flash_fwd(q, k, v, causal, q_chunk, kv_chunk, scale):
+def _flash_fwd(q, k, v, causal, q_chunk, kv_chunk, scale, mesh):
     out, lse = _flash_forward_dispatch(q, k, v, causal, q_chunk, kv_chunk,
-                                       scale)
+                                       scale, mesh)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, q_chunk, kv_chunk, scale, res, do):
+def _flash_bwd(causal, q_chunk, kv_chunk, scale, mesh, res, do):
     """Flash backward: for each (kv, q) chunk pair, recompute
     p = exp(q k^T scale - lse) from the saved stats, then
 

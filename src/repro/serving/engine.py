@@ -433,9 +433,11 @@ class ServeEngine:
         tm.inc("serve.prefill_tokens", int(valid.sum()))
         with tm.span("serve.prefill_chunk", tick=self.tick,
                      tokens=int(valid.sum()), slots=int(active.sum())):
+            # A copy: the host array is bumped below while the tick may
+            # still be reading it (jax aliases host numpy memory on CPU).
             logits, state = self._extend_fn(
                 self.params, jnp.asarray(toks), self._state(),
-                jnp.asarray(self.lengths), jnp.asarray(valid),
+                jnp.asarray(self.lengths.copy()), jnp.asarray(valid),
                 jnp.asarray(active))
         self._set_state(state)
         self.lengths[active] += valid[active]
@@ -464,8 +466,9 @@ class ServeEngine:
         with tm.span("serve.decode_step", tick=self.tick,
                      slots=int(active.sum())):
             logits, state = self._decode_fn(
-                self.params, jnp.asarray(self.next_tok), self._state(),
-                jnp.asarray(self.lengths), jnp.asarray(active))
+                self.params, jnp.asarray(self.next_tok.copy()),
+                self._state(), jnp.asarray(self.lengths.copy()),
+                jnp.asarray(active))
         self._set_state(state)
         self.lengths[active] += 1
         temps = np.array([self.slot_req[s].temperature if active[s] else 0.0

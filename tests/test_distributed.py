@@ -71,7 +71,7 @@ def test_fsdp_adds_data_axis():
 
 
 def test_sharder_guard_on_small_dims():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = sharding.make_mesh((1, 1), ("data", "model"))
     shard = sharding.make_sharder(mesh)
     x = jnp.ones((4, 8, 16))
     y = shard(x, ("batch", "seq", None))
@@ -91,7 +91,7 @@ def test_spmd_8dev_train_step_runs():
         from repro.launch import steps as steps_lib
         from repro.optim.adamw import AdamW
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = sharding.make_mesh((4, 2), ("data", "model"))
         arch = cfgbase.get("tinyllama_1_1b")
         model, cfg = steps_lib.build_model(arch, smoke=True)
         shard = sharding.make_sharder(mesh)
