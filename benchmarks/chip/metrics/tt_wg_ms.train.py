@@ -1,0 +1,10 @@
+"""Device ms per traced training step in the program's ``tt_wg`` named
+scope: the TT weight-gradient plans, the shared dW included.  Ops are
+matched to the scope by their ``op_name`` (``scopes.train_ms``)."""
+
+import importlib
+
+
+def read(run):
+    scopes = importlib.import_module(run["devtrace"].__package__ + ".scopes")
+    return scopes.train_ms(run, "tt_wg")

@@ -676,12 +676,7 @@ def run(compiled: CompiledPlan, tensors: Sequence[jax.Array],
     for t, op in enumerate(compiled.ops):
         for slot in _op_reads(op):
             last_use[slot] = t
-    # Per-op execution spans: under jit these time the *dispatch/trace*
-    # of each kernel (jax is async), eagerly/interpreted they bound the
-    # kernel itself — either way the trace shows which op ran when.
-    _trace = tm.enabled()
     for t, op in enumerate(compiled.ops):
-        _t0 = tm.now_us() if _trace else 0.0
         if isinstance(op, EinsumOp):
             res = _einsum_step(op.step, slots[op.step.lhs],
                                slots[op.step.rhs], accum_dtype)
@@ -730,10 +725,6 @@ def run(compiled: CompiledPlan, tensors: Sequence[jax.Array],
                 res = jnp.transpose(res, op.out_perm)
             out_slot = op.second.out
         slots[out_slot] = res.astype(out_dtype)
-        if _trace:
-            kind = ("einsum" if isinstance(op, EinsumOp)
-                    else "gemm" if isinstance(op, GemmOp) else "chain")
-            tm.complete_span(f"exec.{kind}", _t0, tm.now_us(), op_index=t)
         for slot in _op_reads(op):
             if slot != out_slot and last_use[slot] == t and slot in slots:
                 del slots[slot]

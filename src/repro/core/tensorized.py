@@ -400,10 +400,11 @@ def _tnn_apply(fact: Factorization, opts: csse.SearchOptions, backend: str,
                autotune_flag: bool, mesh, mesh_axes, remat: StashPolicy,
                x: jax.Array, *cores: jax.Array) -> jax.Array:
     fp, _, _ = _plans(fact, x.shape[0], opts)
-    return contraction.execute(fp.plan, [x, *cores], backend=backend,
-                               fused_chain=opts.fused_chain,
-                               tuner=_exec_tuner(backend, autotune_flag),
-                               mesh=mesh, mesh_batch_axes=mesh_axes)
+    with jax.named_scope("tt_fp"):
+        return contraction.execute(fp.plan, [x, *cores], backend=backend,
+                                   fused_chain=opts.fused_chain,
+                                   tuner=_exec_tuner(backend, autotune_flag),
+                                   mesh=mesh, mesh_batch_axes=mesh_axes)
 
 
 def _tnn_fwd(fact, opts, backend, autotune_flag, mesh, mesh_axes, remat,
@@ -427,21 +428,24 @@ def _tnn_bwd(fact, opts, backend, autotune_flag, mesh, mesh_axes, remat,
     exec_kw = dict(backend=backend, fused_chain=opts.fused_chain,
                    tuner=tuner, mesh=mesh, mesh_batch_axes=mesh_axes)
     dy = dy.astype(x.dtype)
-    dx = contraction.execute(bp.plan, [dy, *cores], **exec_kw)
+    with jax.named_scope("tt_bp"):
+        dx = contraction.execute(bp.plan, [dy, *cores], **exec_kw)
     dcores = []
-    if wg_kind == "shared":
-        dw = contraction.execute(dw_res.plan, [x, dy], **exec_kw)
-        for i, w in enumerate(wg):
-            others = tuple(c for j, c in enumerate(cores) if j != i)
-            # The wg-from-dW networks have no batch axis left: mesh execution
-            # degenerates to the single-device path (dW was already reduced).
-            dcores.append(contraction.execute(w.plan, [dw, *others],
-                                              **exec_kw))
-    else:
-        for i, w in enumerate(wg):
-            others = tuple(c for j, c in enumerate(cores) if j != i)
-            dcores.append(contraction.execute(w.plan, [x, dy, *others],
-                                              **exec_kw))
+    with jax.named_scope("tt_wg"):
+        if wg_kind == "shared":
+            dw = contraction.execute(dw_res.plan, [x, dy], **exec_kw)
+            for i, w in enumerate(wg):
+                others = tuple(c for j, c in enumerate(cores) if j != i)
+                # The wg-from-dW networks have no batch axis left: mesh
+                # execution degenerates to the single-device path (dW was
+                # already reduced).
+                dcores.append(contraction.execute(w.plan, [dw, *others],
+                                                  **exec_kw))
+        else:
+            for i, w in enumerate(wg):
+                others = tuple(c for j, c in enumerate(cores) if j != i)
+                dcores.append(contraction.execute(w.plan, [x, dy, *others],
+                                                  **exec_kw))
     return (dx, *dcores)
 
 
@@ -485,11 +489,12 @@ def _tnn_apply_q(fact: Factorization, opts: csse.SearchOptions, backend: str,
     fp, _, _ = _plans(fact, x.shape[0], opts)
     core_rows = list(range(2, 2 + len(cores)))
     scales = _phase_scales(policy, amax_hist, [0] + core_rows, (x,) + cores)
-    return contraction.execute(fp.plan, [x, *cores], backend=backend,
-                               fused_chain=opts.fused_chain,
-                               tuner=_exec_tuner(backend, autotune_flag),
-                               mesh=mesh, mesh_batch_axes=mesh_axes,
-                               policy=policy, input_scales=scales)
+    with jax.named_scope("tt_fp"):
+        return contraction.execute(fp.plan, [x, *cores], backend=backend,
+                                   fused_chain=opts.fused_chain,
+                                   tuner=_exec_tuner(backend, autotune_flag),
+                                   mesh=mesh, mesh_batch_axes=mesh_axes,
+                                   policy=policy, input_scales=scales)
 
 
 def _stash_policy_q(policy: QuantPolicy, remat: StashPolicy) -> StashPolicy:
@@ -530,25 +535,27 @@ def _tnn_q_bwd(fact, opts, backend, autotune_flag, mesh, mesh_axes, policy,
     s_x = scale_from_history(hist[0], amax_x, policy.qmax, policy.margin)
     s_dy, *s_cores = _phase_scales(
         policy, hist, [1] + core_rows, (dy,) + cores)
-    dx = contraction.execute(bp.plan, [dy, *cores],
-                             input_scales=[s_dy, *s_cores], **exec_kw)
+    with jax.named_scope("tt_bp"):
+        dx = contraction.execute(bp.plan, [dy, *cores],
+                                 input_scales=[s_dy, *s_cores], **exec_kw)
     dcores = []
-    if wg_kind == "shared":
-        dw = contraction.execute(dw_res.plan, [x, dy],
-                                 input_scales=[s_x, s_dy], **exec_kw)
-        for i, w in enumerate(wg):
-            others = tuple(c for j, c in enumerate(cores) if j != i)
-            s_others = [s for j, s in enumerate(s_cores) if j != i]
-            dcores.append(contraction.execute(
-                w.plan, [dw, *others], input_scales=[None, *s_others],
-                **exec_kw))
-    else:
-        for i, w in enumerate(wg):
-            others = tuple(c for j, c in enumerate(cores) if j != i)
-            s_others = [s for j, s in enumerate(s_cores) if j != i]
-            dcores.append(contraction.execute(
-                w.plan, [x, dy, *others],
-                input_scales=[s_x, s_dy, *s_others], **exec_kw))
+    with jax.named_scope("tt_wg"):
+        if wg_kind == "shared":
+            dw = contraction.execute(dw_res.plan, [x, dy],
+                                     input_scales=[s_x, s_dy], **exec_kw)
+            for i, w in enumerate(wg):
+                others = tuple(c for j, c in enumerate(cores) if j != i)
+                s_others = [s for j, s in enumerate(s_cores) if j != i]
+                dcores.append(contraction.execute(
+                    w.plan, [dw, *others], input_scales=[None, *s_others],
+                    **exec_kw))
+        else:
+            for i, w in enumerate(wg):
+                others = tuple(c for j, c in enumerate(cores) if j != i)
+                s_others = [s for j, s in enumerate(s_cores) if j != i]
+                dcores.append(contraction.execute(
+                    w.plan, [x, dy, *others],
+                    input_scales=[s_x, s_dy, *s_others], **exec_kw))
     # The state-update channel: roll every history row one step with this
     # step's observed amaxes and deliver the delta as the "gradient".
     # amax_x is the *forward* statistic (stashed exactly under a quantized

@@ -215,14 +215,15 @@ class Attention:
 
     def __call__(self, params: dict, x: jax.Array, positions: jax.Array,
                  shard: Shard = no_shard) -> jax.Array:
-        q, k, v = self._qkv(params, x, positions)
-        q = shard(q, ("batch", "seq", "heads", None))
-        k = shard(k, ("batch", "seq", "kv_heads", None))
-        ctx = blockwise_attention(q, k, v, causal=self.causal,
-                                  q_chunk=self.q_chunk,
-                                  kv_chunk=self.kv_chunk,
-                                  mesh=getattr(shard, "mesh", None))
-        return self._out(params, ctx)
+        with jax.named_scope("attention"):
+            q, k, v = self._qkv(params, x, positions)
+            q = shard(q, ("batch", "seq", "heads", None))
+            k = shard(k, ("batch", "seq", "kv_heads", None))
+            ctx = blockwise_attention(q, k, v, causal=self.causal,
+                                      q_chunk=self.q_chunk,
+                                      kv_chunk=self.kv_chunk,
+                                      mesh=getattr(shard, "mesh", None))
+            return self._out(params, ctx)
 
     def prefill(self, params, x, positions, max_len: int, shard: Shard = no_shard):
         """Run full attention and return the populated KV cache."""
@@ -247,44 +248,45 @@ class Attention:
         slot then writes its k/v at its own offset and masks to its own
         depth, which is what lets the serving engine mix requests of
         different lengths in one decode tick."""
-        B = x.shape[0]
-        H, KV, D = self._shapes
-        length = cache.length
-        per_slot = jnp.ndim(length) == 1
-        if per_slot:
-            positions = length[:, None]
-        else:
-            positions = jnp.broadcast_to(length, (B, 1))
-        q, k, v = self._qkv(params, x, positions)
-        if per_slot:
-            upd = jax.vmap(
-                lambda buf, new, start: jax.lax.dynamic_update_slice_in_dim(
-                    buf, new, start, axis=0))
-            kc = upd(cache.k, k, length)
-            vc = upd(cache.v, v, length)
-        else:
-            kc = jax.lax.dynamic_update_slice_in_dim(cache.k, k, length,
-                                                     axis=1)
-            vc = jax.lax.dynamic_update_slice_in_dim(cache.v, v, length,
-                                                     axis=1)
-        new_cache = KVCache(kc, vc, length + 1)
+        with jax.named_scope("attention"):
+            B = x.shape[0]
+            H, KV, D = self._shapes
+            length = cache.length
+            per_slot = jnp.ndim(length) == 1
+            if per_slot:
+                positions = length[:, None]
+            else:
+                positions = jnp.broadcast_to(length, (B, 1))
+            q, k, v = self._qkv(params, x, positions)
+            if per_slot:
+                upd = jax.vmap(
+                    lambda buf, new, start: jax.lax.dynamic_update_slice_in_dim(
+                        buf, new, start, axis=0))
+                kc = upd(cache.k, k, length)
+                vc = upd(cache.v, v, length)
+            else:
+                kc = jax.lax.dynamic_update_slice_in_dim(cache.k, k, length,
+                                                         axis=1)
+                vc = jax.lax.dynamic_update_slice_in_dim(cache.v, v, length,
+                                                         axis=1)
+            new_cache = KVCache(kc, vc, length + 1)
 
-        groups = H // KV
-        qg = q.reshape(B, 1, KV, groups, D)
-        scores = jnp.einsum("bqkgd,btkd->bkgqt", qg.astype(jnp.float32),
-                            kc.astype(jnp.float32)) / math.sqrt(D)
-        t_idx = jnp.arange(kc.shape[1])
-        if per_slot:
-            mask = (t_idx[None, None, None, None, :]
-                    <= length[:, None, None, None, None])
-        else:
-            mask = t_idx[None, None, None, None, :] <= length
-        scores = jnp.where(mask, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bkgqt,btkd->bqkgd", probs,
-                         vc.astype(jnp.float32)).astype(x.dtype)
-        ctx = ctx.reshape(B, 1, H, D)
-        return self._out(params, ctx), new_cache
+            groups = H // KV
+            qg = q.reshape(B, 1, KV, groups, D)
+            scores = jnp.einsum("bqkgd,btkd->bkgqt", qg.astype(jnp.float32),
+                                kc.astype(jnp.float32)) / math.sqrt(D)
+            t_idx = jnp.arange(kc.shape[1])
+            if per_slot:
+                mask = (t_idx[None, None, None, None, :]
+                        <= length[:, None, None, None, None])
+            else:
+                mask = t_idx[None, None, None, None, :] <= length
+            scores = jnp.where(mask, scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1)
+            ctx = jnp.einsum("bkgqt,btkd->bqkgd", probs,
+                             vc.astype(jnp.float32)).astype(x.dtype)
+            ctx = ctx.reshape(B, 1, H, D)
+            return self._out(params, ctx), new_cache
 
     def extend(self, params, x, cache: KVCache, shard: Shard = no_shard,
                valid: jax.Array | None = None):
@@ -300,38 +302,39 @@ class Attention:
         garbage.  Logits come back for every chunk position ([B, C, ...]);
         the caller reads row ``valid-1`` of slots whose prompt completed —
         in-chunk queries past valid produce don't-care rows."""
-        B, C, _ = x.shape
-        H, KV, D = self._shapes
-        length = cache.length
-        if jnp.ndim(length) == 0:
-            length = jnp.full((B,), length, jnp.int32)
-        positions = length[:, None] + jnp.arange(C)[None, :]      # [B, C]
-        q, k, v = self._qkv(params, x, positions)
-        if valid is not None:
-            keep = (jnp.arange(C)[None, :] < valid[:, None])[..., None, None]
-            k = jnp.where(keep, k, jnp.zeros((), k.dtype))
-            v = jnp.where(keep, v, jnp.zeros((), v.dtype))
-        upd = jax.vmap(
-            lambda buf, new, start: jax.lax.dynamic_update_slice_in_dim(
-                buf, new.astype(buf.dtype), start, axis=0))
-        kc = upd(cache.k, k, length)
-        vc = upd(cache.v, v, length)
-        adv = C if valid is None else valid
-        new_cache = KVCache(kc, vc, cache.length + adv)
+        with jax.named_scope("attention"):
+            B, C, _ = x.shape
+            H, KV, D = self._shapes
+            length = cache.length
+            if jnp.ndim(length) == 0:
+                length = jnp.full((B,), length, jnp.int32)
+            positions = length[:, None] + jnp.arange(C)[None, :]      # [B, C]
+            q, k, v = self._qkv(params, x, positions)
+            if valid is not None:
+                keep = (jnp.arange(C)[None, :] < valid[:, None])[..., None, None]
+                k = jnp.where(keep, k, jnp.zeros((), k.dtype))
+                v = jnp.where(keep, v, jnp.zeros((), v.dtype))
+            upd = jax.vmap(
+                lambda buf, new, start: jax.lax.dynamic_update_slice_in_dim(
+                    buf, new.astype(buf.dtype), start, axis=0))
+            kc = upd(cache.k, k, length)
+            vc = upd(cache.v, v, length)
+            adv = C if valid is None else valid
+            new_cache = KVCache(kc, vc, cache.length + adv)
 
-        groups = H // KV
-        qg = q.reshape(B, C, KV, groups, D)
-        scores = jnp.einsum("bckgd,btkd->bkgct", qg.astype(jnp.float32),
-                            kc.astype(jnp.float32)) / math.sqrt(D)
-        t_idx = jnp.arange(kc.shape[1])
-        mask = (t_idx[None, None, None, None, :]
-                <= positions[:, None, None, :, None])
-        scores = jnp.where(mask, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bkgct,btkd->bckgd", probs,
-                         vc.astype(jnp.float32)).astype(x.dtype)
-        ctx = ctx.reshape(B, C, H, D)
-        return self._out(params, ctx), new_cache
+            groups = H // KV
+            qg = q.reshape(B, C, KV, groups, D)
+            scores = jnp.einsum("bckgd,btkd->bkgct", qg.astype(jnp.float32),
+                                kc.astype(jnp.float32)) / math.sqrt(D)
+            t_idx = jnp.arange(kc.shape[1])
+            mask = (t_idx[None, None, None, None, :]
+                    <= positions[:, None, None, :, None])
+            scores = jnp.where(mask, scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1)
+            ctx = jnp.einsum("bkgct,btkd->bckgd", probs,
+                             vc.astype(jnp.float32)).astype(x.dtype)
+            ctx = ctx.reshape(B, C, H, D)
+            return self._out(params, ctx), new_cache
 
 
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

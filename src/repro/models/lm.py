@@ -212,11 +212,12 @@ class LM:
 
     def _logits(self, params, x):
         c = self.cfg
-        if c.tie_embeddings:
-            w = params["embed"].astype(c.compute_dtype)
-            return einsum_f32("btd,vd->btv", x, w).astype(c.compute_dtype)
-        return Dense(c.d_model, c.vocab, param_dtype=c.param_dtype,
-                     compute_dtype=c.compute_dtype)(params["lm_head"], x)
+        with jax.named_scope("lm_head"):
+            if c.tie_embeddings:
+                w = params["embed"].astype(c.compute_dtype)
+                return einsum_f32("btd,vd->btv", x, w).astype(c.compute_dtype)
+            return Dense(c.d_model, c.vocab, param_dtype=c.param_dtype,
+                         compute_dtype=c.compute_dtype)(params["lm_head"], x)
 
     def _moe_apply(self, lp_mlp, y, shard):
         """Group tokens by batch row (groups shard over `data`)."""
@@ -370,19 +371,20 @@ class LM:
         mask = batch.get("mask")
         if mask is None:
             mask = jnp.ones(targets.shape, jnp.float32)
-        lf = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(lf, axis=-1)
-        # gold logit via masked reduction, not take_along_axis: a gather
-        # along the vocab axis would force an all-gather of the
-        # vocab-sharded logits; the where+sum stays shard-local and reduces
-        # with a tiny all-reduce.
-        vocab_iota = jax.lax.broadcasted_iota(jnp.int32, lf.shape,
-                                              lf.ndim - 1)
-        gold = jnp.sum(jnp.where(vocab_iota == targets[..., None], lf, 0.0),
-                       axis=-1)
-        nll = (lse - gold) * mask
-        denom = jnp.maximum(jnp.sum(mask), 1.0)
-        loss = jnp.sum(nll) / denom
+        with jax.named_scope("lm_head"):
+            lf = logits.astype(jnp.float32)
+            lse = jax.nn.logsumexp(lf, axis=-1)
+            # gold logit via masked reduction, not take_along_axis: a gather
+            # along the vocab axis would force an all-gather of the
+            # vocab-sharded logits; the where+sum stays shard-local and
+            # reduces with a tiny all-reduce.
+            vocab_iota = jax.lax.broadcasted_iota(jnp.int32, lf.shape,
+                                                  lf.ndim - 1)
+            gold = jnp.sum(jnp.where(vocab_iota == targets[..., None], lf,
+                                     0.0), axis=-1)
+            nll = (lse - gold) * mask
+            denom = jnp.maximum(jnp.sum(mask), 1.0)
+            loss = jnp.sum(nll) / denom
         return loss, {"nll": loss, "tokens": jnp.sum(mask)}
 
     def loss(self, params: dict, batch: dict, shard: Shard = no_shard

@@ -186,7 +186,8 @@ class ServeEngine:
                 return n
             return jnp.where(m, n, o)
 
-        return jax.tree.map(sel, new, old)
+        with jax.named_scope("engine_select"):
+            return jax.tree.map(sel, new, old)
 
     def _build_step_fns(self):
         model, shard, policy = self.model, self.shard, self.kv_policy
@@ -350,7 +351,8 @@ class ServeEngine:
             sub, logits.astype(jnp.float32)
             / jnp.maximum(jnp.asarray(temps)[:, None], 1e-4))
         pick = jnp.where(jnp.asarray(temps) > 0, temped, greedy)
-        return np.asarray(pick, np.int32)
+        with tm.span("serve.fetch", tick=self.tick):
+            return np.asarray(pick, np.int32)
 
     def _append_token(self, slot: int, tok: int) -> None:
         """Record a sampled token; finish the request when EOS or the
@@ -379,19 +381,15 @@ class ServeEngine:
     def _emit_request_trace(self, req: Request, slot: int) -> None:
         """Reconstruct the finished request's lifecycle as trace spans.
 
-        The engine keeps monotonic stamps (submit/admit/first/done); at
-        finish they are re-anchored onto the tracer clock — "now" maps
-        to now, deltas are preserved — and laid out on virtual lanes:
+        The engine keeps monotonic stamps (submit/admit/first/done), the
+        tracer's own clock (``tm.mono_us``); at finish they are laid out
+        on virtual lanes:
         queue-wait on the shared ``queue`` lane, prefill (admission to
         first token) and decode on the request's ``slot<n>`` lane, so
         overlapping requests render side by side in Perfetto."""
         if not tm.enabled() or req.t_submit is None:
             return
-        mono, now = time.monotonic(), tm.now_us()
-
-        def at(t: float) -> float:
-            return now - (mono - t) * 1e6
-
+        at = tm.mono_us
         lane = f"slot{slot}"
         if req.t_admit is not None:
             tm.complete_span("serve.queue_wait", at(req.t_submit),
@@ -405,9 +403,6 @@ class ServeEngine:
                              at(req.t_done), lane=lane, rid=req.rid,
                              tokens=len(req.out_tokens))
         tm.inc("serve.completed")
-        tm.event("serve.request_done", rid=req.rid,
-                 tokens=len(req.out_tokens), ttft_s=req.ttft_s,
-                 total_s=req.t_done - req.t_submit)
 
     def _prefill_tick(self) -> None:
         B, C = self.batch, self.prefill_chunk
@@ -439,24 +434,24 @@ class ServeEngine:
                 self.params, jnp.asarray(toks), self._state(),
                 jnp.asarray(self.lengths.copy()), jnp.asarray(valid),
                 jnp.asarray(active))
-        self._set_state(state)
-        self.lengths[active] += valid[active]
-        self.prefill_pos[active] += valid[active]
+            self._set_state(state)
+            self.lengths[active] += valid[active]
+            self.prefill_pos[active] += valid[active]
 
-        finishing = [s for s in np.nonzero(active)[0]
-                     if self.prefill_pos[s] >= len(self.slot_req[s].prompt)]
-        if finishing:
-            # gather + sample at full batch width so the eager sampling
-            # kernels compile once (warmup covers them), regardless of how
-            # many slots finish this tick
-            cols = jnp.asarray(np.maximum(valid - 1, 0))
-            last = logits[jnp.arange(B), cols]            # [B, V]
-            temps = np.zeros(B, np.float32)
-            for s in finishing:
-                temps[s] = self.slot_req[s].temperature
-            picks = self._sample(last, temps)
-            for s in finishing:
-                self._append_token(int(s), int(picks[s]))
+            finishing = [s for s in np.nonzero(active)[0] if
+                         self.prefill_pos[s] >= len(self.slot_req[s].prompt)]
+            if finishing:
+                # gather + sample at full batch width so the eager sampling
+                # kernels compile once (warmup covers them), regardless of
+                # how many slots finish this tick
+                cols = jnp.asarray(np.maximum(valid - 1, 0))
+                last = logits[jnp.arange(B), cols]            # [B, V]
+                temps = np.zeros(B, np.float32)
+                for s in finishing:
+                    temps[s] = self.slot_req[s].temperature
+                picks = self._sample(last, temps)
+                for s in finishing:
+                    self._append_token(int(s), int(picks[s]))
 
     def _decode_tick(self) -> None:
         active = self.phase == DECODE
@@ -469,13 +464,14 @@ class ServeEngine:
                 self.params, jnp.asarray(self.next_tok.copy()),
                 self._state(), jnp.asarray(self.lengths.copy()),
                 jnp.asarray(active))
-        self._set_state(state)
-        self.lengths[active] += 1
-        temps = np.array([self.slot_req[s].temperature if active[s] else 0.0
-                          for s in range(self.batch)], np.float32)
-        picks = self._sample(logits, temps)
-        for slot in np.nonzero(active)[0]:
-            self._append_token(int(slot), int(picks[slot]))
+            self._set_state(state)
+            self.lengths[active] += 1
+            temps = np.array([self.slot_req[s].temperature if active[s]
+                              else 0.0 for s in range(self.batch)],
+                             np.float32)
+            picks = self._sample(logits, temps)
+            for slot in np.nonzero(active)[0]:
+                self._append_token(int(slot), int(picks[slot]))
 
     # -- main loop ----------------------------------------------------------
 
@@ -483,9 +479,11 @@ class ServeEngine:
         """One tick: admit, prefill chunk, decode.  Returns the requests
         that completed during the tick."""
         before = len(self.completed)
-        self._admit()
-        self._prefill_tick()
-        self._decode_tick()
+        with tm.span("serve.tick", tick=self.tick):
+            with tm.span("serve.admit", tick=self.tick):
+                self._admit()
+            self._prefill_tick()
+            self._decode_tick()
         self.tick += 1
         return self.completed[before:]
 

@@ -30,12 +30,14 @@ from repro.telemetry.log import Logger, get_logger
 from repro.telemetry.tracer import (
     SpanContext, Tracer, attach, complete_span, configure, counters,
     current_context, drift, drift_records, enabled, event, finalize, inc,
-    now_us, reset, sample, snapshot, span, suspended, warn_once_key,
+    mono_us, now_us, reset, sample, snapshot, span, suspended,
+    sync_profiler, warn_once_key,
 )
 
 __all__ = [
     "Logger", "SpanContext", "Tracer", "attach", "complete_span",
     "configure", "counters", "current_context", "drift", "drift_records",
-    "enabled", "event", "finalize", "get_logger", "inc", "now_us",
-    "reset", "sample", "snapshot", "span", "suspended", "warn_once_key",
+    "enabled", "event", "finalize", "get_logger", "inc", "mono_us",
+    "now_us", "reset", "sample", "snapshot", "span", "suspended",
+    "sync_profiler", "warn_once_key",
 ]

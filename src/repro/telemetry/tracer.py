@@ -25,10 +25,13 @@ every entry point is one attribute load and a falsy check — no dict
 building, no clock reads — so instrumented hot paths cost nothing
 measurable; tests pin this (``tests/test_telemetry.py``).
 
-Timestamps are microseconds since the tracer epoch
-(``time.perf_counter`` based), the unit Chrome trace events use.  This
-module is dependency-free on purpose: no jax, no repro.core — every
-other layer may import it without cycles.
+Timestamps are microseconds since the tracer epoch, on
+``time.monotonic`` — the clock the serving engine's request stamps and
+the chip benchmark read — so :func:`mono_us` places any monotonic
+reading on the tracer's clock.  :func:`sync_profiler` ties that clock to
+a JAX profiler capture.  This module is dependency-free on purpose: no
+jax, no repro.core — every other layer may import it without cycles
+(the jax pieces live in ``jaxbridge`` and load lazily).
 """
 
 from __future__ import annotations
@@ -82,7 +85,7 @@ class Tracer:
         self._stream = None          # open JSONL handle (path *.jsonl)
         self._lock = threading.Lock()
         self._next_id = 1
-        self._t0 = time.perf_counter()
+        self._t0 = time.monotonic()
         self._pid = os.getpid()
         self._tids: dict[object, int] = {}
         self._warned: set[str] = set()
@@ -90,7 +93,7 @@ class Tracer:
     # -- clock / ids --------------------------------------------------------
 
     def now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+        return (time.monotonic() - self._t0) * 1e6
 
     def _tid(self, key: object | None = None) -> int:
         """Small stable lane id for a thread (default: the calling
@@ -186,7 +189,27 @@ def configure(path: str | None = None, *,
         if path.endswith(".jsonl"):
             t._stream = open(path, "w")
     t._tid()       # lane 0 = the configuring (main) thread
+    from repro.telemetry import jaxbridge
+    jaxbridge.watch_compiles(_on_compile)
     return t
+
+
+def _on_compile(seconds: float, fun_name: str | None) -> None:
+    """One executable built by JAX (a backend compile or a persistent
+    compile-cache load): count it, record a ``jax.compile`` span ending
+    now, and mark it on a running profiler capture when bridging."""
+    t = _TRACER
+    if not t.enabled:
+        return
+    inc("jax.compiles")
+    end = t.now_us()
+    complete_span("jax.compile", end - seconds * 1e6, end, fun=fun_name)
+    if t.jax_bridge:
+        from repro.telemetry import jaxbridge
+        ann = jaxbridge.annotation("jax.compile")
+        if ann is not None:
+            with ann:
+                pass
 
 
 def finalize() -> None:
@@ -216,7 +239,7 @@ def reset() -> None:
     t._tids.clear()
     t._warned.clear()
     t._next_id = 1
-    t._t0 = time.perf_counter()
+    t._t0 = time.monotonic()
     _tls.span = None
 
 
@@ -243,6 +266,8 @@ class _Span:
         _tls.span = SpanContext(self.span_id, self.name)
         if t.jax_bridge:
             from repro.telemetry import jaxbridge
+            if jaxbridge.new_profiler_session():
+                _sync(t)
             self._ann = jaxbridge.annotation(self.name)
             if self._ann is not None:
                 self._ann.__enter__()
@@ -292,6 +317,41 @@ def now_us() -> float:
     """Microseconds since the tracer epoch (0.0 when disabled)."""
     t = _TRACER
     return t.now_us() if t.enabled else 0.0
+
+
+def mono_us(t_mono: float) -> float:
+    """A ``time.monotonic()`` reading on the tracer clock (µs)."""
+    return (t_mono - _TRACER._t0) * 1e6
+
+
+def sync_profiler() -> float | None:
+    """Tie the tracer clock to a running JAX profiler capture: open a
+    ``tracer.sync`` profiler annotation and record a ``tracer.sync``
+    instant at the tracer time it opened.  The annotation's start on the
+    profiler's clock minus the instant's ``ts`` is the offset that places
+    every tracer record, ``complete_span`` ones included, on the
+    capture's timeline.  The bridge calls it at the first bridged span of
+    each capture; call it right after ``jax.profiler.start_trace`` to sync
+    before any span opens.  Returns the instant's ``ts``, or None when
+    tracing is off or jax is missing."""
+    t = _TRACER
+    if not t.enabled:
+        return None
+    from repro.telemetry import jaxbridge
+    jaxbridge.new_profiler_session()      # this capture is now synced
+    return _sync(t)
+
+
+def _sync(t: Tracer) -> float | None:
+    from repro.telemetry import jaxbridge
+    ann = jaxbridge.annotation("tracer.sync")
+    if ann is None:
+        return None
+    with ann:
+        ts = t.now_us()
+    t._record({"type": "instant", "name": "tracer.sync", "ts": ts,
+               "pid": t._pid, "tid": t._tid(), "args": {}})
+    return ts
 
 
 def current_context() -> SpanContext | None:

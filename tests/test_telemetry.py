@@ -17,6 +17,7 @@ counter guarantees the instrumented layers make.
 """
 
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -400,3 +401,123 @@ def test_warn_once_mirrors_into_trace(capsys, monkeypatch):
     assert out.count("WARN") == 1
     events = [e for e in tm.snapshot() if e["type"] == "instant"]
     assert len(events) == 1 and events[0]["name"] == "log.warn"
+
+
+# ---------------------------------------------------------------------------
+# The profiler's clock and the compile counter
+# ---------------------------------------------------------------------------
+
+
+def test_sync_profiler_places_records_on_the_profiler_clock(tmp_path):
+    """In a real CPU capture, the ``tracer.sync`` pair places a bridged
+    span and a ``complete_span`` of the same stretch within 1 ms of the
+    profiler's own event for the bridged span."""
+    from benchmarks.chip import devtrace, scopes
+
+    tm.configure(jax_bridge=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert tm.sync_profiler() is not None
+        time.sleep(0.02)
+        t0 = tm.now_us()
+        with tm.span("probe.bridged"):
+            time.sleep(0.01)
+        tm.complete_span("probe.complete", t0, tm.now_us())
+    finally:
+        jax.profiler.stop_trace()
+    trace = devtrace.load(str(tmp_path))
+    (twin,) = [(s, e) for n, s, e in trace["spans"] if n == "probe.bridged"]
+    records = {e["name"]: e for e in tm.snapshot() if e["type"] == "span"}
+    for name in ("probe.bridged", "probe.complete"):
+        s, e = scopes.place(trace, records[name], tm.snapshot())
+        assert abs(s - twin[0]) < 1e-3 and abs(e - twin[1]) < 1e-3, name
+
+
+def test_bridge_syncs_at_the_first_span_of_a_capture(tmp_path):
+    tm.configure(jax_bridge=True)
+    with tm.span("before"):
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            with tm.span("inside"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    syncs = [e for e in tm.snapshot() if e["name"] == "tracer.sync"]
+    assert len(syncs) == 1
+
+
+def test_compile_counter_counts_recompiles_only():
+    tm.configure()
+    f = jax.jit(lambda x: jnp.sin(x) * 3.0)
+    f(np.ones(7, np.float32)).block_until_ready()
+    n = tm.counters().get("jax.compiles", 0)
+    assert n >= 1
+    f(np.ones(7, np.float32)).block_until_ready()
+    assert tm.counters()["jax.compiles"] == n, "same shapes: no compile"
+    f(np.ones(9, np.float32)).block_until_ready()
+    assert tm.counters()["jax.compiles"] == n + 1, "new shape: one compile"
+    spans = [e for e in tm.snapshot() if e["type"] == "span"
+             and e["name"] == "jax.compile"]
+    assert len(spans) == n + 1 and all(e["dur"] >= 0 for e in spans)
+
+
+# ---------------------------------------------------------------------------
+# Named scopes in the compiled programs
+# ---------------------------------------------------------------------------
+
+
+def _op_names(compiled) -> list[str]:
+    import re
+    return re.findall(r'op_name="([^"]*)"', compiled.as_text())
+
+
+def _has_scope(names, scope) -> bool:
+    import re
+    pat = re.compile(rf"(^|[/(]){scope}([/)]|$)")
+    return any(pat.search(n) for n in names)
+
+
+def test_train_step_hlo_names_its_layers():
+    """The TT phases, attention and the LM head stay named in the compiled
+    smoke-size train step, whatever scan, remat or grad do to the path."""
+    import dataclasses
+
+    from repro.configs import base as cfgbase
+    from repro.launch import steps
+    from repro.optim.adamw import AdamW
+
+    arch = cfgbase.get("internlm2_1_8b")
+    tnn = dataclasses.replace(arch.tnn_default, enabled=True, rank=8,
+                              num_factors=2)
+    model, _ = steps.build_model(arch, tnn=tnn, smoke=True)
+    opt = AdamW(lr=1e-3)
+    step = jax.jit(steps.make_train_step(model, opt, lambda x, a: x))
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    state = {"params": params, "opt": jax.eval_shape(opt.init, params)}
+    tok = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    names = _op_names(step.lower(state, {"inputs": tok, "targets": tok})
+                      .compile())
+    for scope in ("tt_fp", "tt_bp", "tt_wg", "attention", "lm_head"):
+        assert _has_scope(names, scope), scope
+
+
+def test_engine_programs_name_the_select():
+    from repro.configs import base as cfgbase
+    from repro.launch import steps
+    from repro.serving.engine import ServeEngine
+
+    model, _ = steps.build_model(cfgbase.get("internlm2_1_8b"), smoke=True)
+    eng = ServeEngine(model, model.init(jax.random.key(0)), batch_size=2,
+                      max_len=32, prefill_chunk=4)
+    vec = jnp.zeros(2, jnp.int32)
+    off = jnp.zeros(2, bool)
+    ext = eng._extend_fn.lower(eng.params, jnp.zeros((2, 4), jnp.int32),
+                               eng._state(), vec, vec, off).compile()
+    dec = eng._decode_fn.lower(eng.params, vec, eng._state(), vec,
+                               off).compile()
+    for compiled in (ext, dec):
+        names = _op_names(compiled)
+        assert _has_scope(names, "engine_select")
+        assert _has_scope(names, "attention")
