@@ -154,6 +154,24 @@ class KVCache(NamedTuple):
                           # instead (co-batched requests at different depths)
 
 
+def _keep_frozen(buf: jax.Array, new: jax.Array, start: jax.Array,
+                 valid: jax.Array) -> jax.Array:
+    """The rows to write into ``buf`` ([B, T, ...]) at each slot's
+    ``start`` ([B]): ``new`` ([B, C, ...]) where ``valid > 0``, and where
+    ``valid == 0`` the C rows already there, so that the write leaves a
+    frozen slot exactly as it was.  Reads only the rows to be written,
+    never the whole buffer."""
+    with jax.named_scope("engine_select"):
+        # One dynamic_slice per slot: a vmapped one lowers to a gather,
+        # for which the TPU compiler re-lays out the whole buffer.
+        size = (1, new.shape[1]) + buf.shape[2:]
+        old = jnp.concatenate([jax.lax.dynamic_slice(
+            buf, (b, start[b]) + (0,) * (buf.ndim - 2), size)
+            for b in range(buf.shape[0])])
+        keep = (valid > 0).reshape((-1,) + (1,) * (new.ndim - 1))
+        return jnp.where(keep, new.astype(buf.dtype), old)
+
+
 @dataclasses.dataclass(frozen=True)
 class Attention:
     d_model: int
@@ -240,14 +258,21 @@ class Attention:
         )
         return self._out(params, ctx), cache
 
-    def decode_step(self, params, x, cache: KVCache, shard: Shard = no_shard):
+    def decode_step(self, params, x, cache: KVCache, shard: Shard = no_shard,
+                    valid: jax.Array | None = None):
         """One-token decode.  x: [B, 1, d_model].
 
         ``cache.length`` is a scalar (every slot at the same depth — the
         historical path, bit-identical) or a per-slot ``[B]`` vector: each
         slot then writes its k/v at its own offset and masks to its own
         depth, which is what lets the serving engine mix requests of
-        different lengths in one decode tick."""
+        different lengths in one decode tick.
+
+        ``valid`` ([B] int32 of 0 or 1, None = every slot) freezes the
+        slots with 0: their write at ``length`` puts back the row already
+        there, and their length does not advance.  Only that one row per
+        slot is read and selected, never the whole buffer; a frozen slot's
+        logits are don't-care."""
         with jax.named_scope("attention"):
             B = x.shape[0]
             H, KV, D = self._shapes
@@ -258,6 +283,9 @@ class Attention:
             else:
                 positions = jnp.broadcast_to(length, (B, 1))
             q, k, v = self._qkv(params, x, positions)
+            if valid is not None:
+                k = _keep_frozen(cache.k, k, positions[:, 0], valid)
+                v = _keep_frozen(cache.v, v, positions[:, 0], valid)
             if per_slot:
                 upd = jax.vmap(
                     lambda buf, new, start: jax.lax.dynamic_update_slice_in_dim(
@@ -269,7 +297,8 @@ class Attention:
                                                          axis=1)
                 vc = jax.lax.dynamic_update_slice_in_dim(cache.v, v, length,
                                                          axis=1)
-            new_cache = KVCache(kc, vc, length + 1)
+            new_cache = KVCache(kc, vc, length + (1 if valid is None
+                                                  else valid))
 
             groups = H // KV
             qg = q.reshape(B, 1, KV, groups, D)
@@ -295,13 +324,17 @@ class Attention:
         or per-slot [B].
 
         ``valid`` ([B] int32, None = whole chunk) marks how many of the C
-        tokens are real per slot.  k/v beyond a slot's valid count are
-        written as zeros: they sit past the advanced length so the causal
-        mask never exposes them (decode overwrites them in order later),
-        and zeros keep the quantized-KV running amax clean of padding
-        garbage.  Logits come back for every chunk position ([B, C, ...]);
-        the caller reads row ``valid-1`` of slots whose prompt completed —
-        in-chunk queries past valid produce don't-care rows."""
+        tokens are real per slot.  In a slot with ``valid > 0``, k/v
+        beyond its valid count are written as zeros: they sit past the
+        advanced length so the causal mask never exposes them (decode
+        overwrites them in order later), and zeros keep the quantized-KV
+        running amax clean of padding garbage.  A slot with ``valid == 0``
+        is frozen: it writes back its own C rows as they were, so the call
+        leaves its k/v and length exactly as it found them.  Logits come
+        back for every chunk position ([B, C, ...]); the caller reads row
+        ``valid-1`` of slots whose prompt completed — in-chunk queries
+        past valid, and every query of a frozen slot, produce don't-care
+        rows."""
         with jax.named_scope("attention"):
             B, C, _ = x.shape
             H, KV, D = self._shapes
@@ -314,6 +347,8 @@ class Attention:
                 keep = (jnp.arange(C)[None, :] < valid[:, None])[..., None, None]
                 k = jnp.where(keep, k, jnp.zeros((), k.dtype))
                 v = jnp.where(keep, v, jnp.zeros((), v.dtype))
+                k = _keep_frozen(cache.k, k, length, valid)
+                v = _keep_frozen(cache.v, v, length, valid)
             upd = jax.vmap(
                 lambda buf, new, start: jax.lax.dynamic_update_slice_in_dim(
                     buf, new.astype(buf.dtype), start, axis=0))

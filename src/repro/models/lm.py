@@ -435,9 +435,18 @@ class LM:
     # -- decode -------------------------------------------------------------------
 
     def decode_step(self, params: dict, token: jax.Array, cache: DecodeCache,
-                    shard: Shard = no_shard) -> tuple[jax.Array, DecodeCache]:
-        """token: [B] ids (or [B, D] embeds) -> (logits [B, V], new cache)."""
+                    shard: Shard = no_shard, valid: jax.Array | None = None
+                    ) -> tuple[jax.Array, DecodeCache]:
+        """token: [B] ids (or [B, D] embeds) -> (logits [B, V], new cache).
+
+        ``valid`` ([B] int32 of 0 or 1, None = every slot; attention blocks
+        only) freezes the slots with 0 inside each layer's KV write, as
+        :meth:`Attention.decode_step` says: their k/v and length come back
+        as they went in."""
         c = self.cfg
+        if valid is not None and c.block != "attn":
+            raise ValueError("decode_step(valid=...) requires an "
+                             "attention-block model")
         B = token.shape[0]
         inputs = token[:, None] if token.ndim == 1 else token[:, None, :]
         x = self._embed(params, inputs, shard)
@@ -449,7 +458,8 @@ class LM:
                 lp, kv = scan_in
                 lkv = KVCache(kv.k, kv.v, pos)
                 h, new_kv = self.attn.decode_step(
-                    lp["attn"], rmsnorm(lp["ln1"], x, c.norm_eps), lkv, shard)
+                    lp["attn"], rmsnorm(lp["ln1"], x, c.norm_eps), lkv, shard,
+                    valid=valid)
                 x = x + h
                 y = rmsnorm(lp["ln2"], x, c.norm_eps)
                 if c.moe:
@@ -528,7 +538,8 @@ class LM:
         x = rmsnorm(params["ln_f"], x, c.norm_eps)
         logits = self._logits(params, x)[:, 0]
         return logits, DecodeCache(layers=new_layers, shared=new_shared,
-                                   length=cache.length + 1)
+                                   length=cache.length + (1 if valid is None
+                                                          else valid))
 
     # -- chunked prefill (serving) --------------------------------------------
 

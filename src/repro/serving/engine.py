@@ -29,12 +29,17 @@ its neighbours are doing (no left-padding, no cross-slot contamination
 arrays are the authoritative slot state; the device cache's ``length``
 is overwritten from them before every call.
 
-Models without a native ``extend`` (SSM/hybrid blocks) prefill through
-a sequential fallback: a ``lax.scan`` of ``decode_step`` over chunk
-columns with per-slot freezing, so the engine stays model-agnostic.
-Inactive slots are frozen out of every call by a per-leaf batch-axis
-select — a garbage write from a padded lane can never corrupt a live
-slot's state (or, in the quantized path, pollute the monotone amax).
+Inactive slots are frozen out of every call, so a garbage write from a
+padded lane can never corrupt a live slot's state.  With a native
+``extend`` and a bf16 cache, the model does it inside its own KV write:
+``extend`` and ``decode_step`` take ``valid`` and put back a frozen
+slot's rows where they would write (a chunk's rows per slot and layer,
+never the whole cache).  Models without a native ``extend`` (SSM/hybrid
+blocks) prefill through a sequential fallback, a ``lax.scan`` of
+``decode_step`` over chunk columns, and they and the quantized cache
+freeze by a per-leaf batch-axis select over the whole state (SSM states
+have no rows to mask, and the quantized path must keep padding out of
+its monotone amax); so does the slot-zeroing at admission.
 """
 
 from __future__ import annotations
@@ -169,11 +174,15 @@ class ServeEngine:
             self._layer_len = cache.layers.length   # [L] bookkeeping shape
 
     def _select(self, active, new, old):
-        """Per-leaf batch-axis select: inactive slots keep their old
-        state.  Axis rule: every stacked per-layer buffer in this repo is
-        >= 3-D with batch on axis 1 ([L, B, ...]), per-slot vectors are
-        1-/2-D with batch on axis 0 — checked in that order, so the rule
-        stays correct when num_layers happens to equal batch_size.
+        """Per-leaf batch-axis select over the whole state: inactive slots
+        keep their old state.  It serves the sequential fallback and the
+        slot-zeroing at admission; the quantized cache selects in
+        ``requant``, and the native bf16 path freezes inside the model's
+        KV write instead.  Axis rule: every stacked per-layer buffer in
+        this repo is >= 3-D with batch on axis 1 ([L, B, ...]), per-slot
+        vectors are 1-/2-D with batch on axis 0 — checked in that order,
+        so the rule stays correct when num_layers happens to equal
+        batch_size.
         Leaves without a batch axis pass through from ``new``."""
         B = self.batch
 
@@ -213,15 +222,28 @@ class ServeEngine:
                 return jnp.transpose(logits, (1, 0, 2)), cache
 
         if policy is None:
-            def extend_fn(params, toks, cache, lengths, valid, active):
-                cache = cache._replace(length=lengths)
-                logits, new = extend_raw(params, toks, cache, valid)
-                return logits, self._select(active, new, cache)
+            if self._native_extend:
+                # Inactive slots have valid == 0: the model freezes them
+                # inside its KV write, and their lengths advance by 0.
+                def extend_fn(params, toks, cache, lengths, valid, active):
+                    cache = cache._replace(length=lengths)
+                    return extend_raw(params, toks, cache, valid)
 
-            def decode_fn(params, tok, cache, lengths, active):
-                cache = cache._replace(length=lengths)
-                logits, new = model.decode_step(params, tok, cache, shard)
-                return logits, self._select(active, new, cache)
+                def decode_fn(params, tok, cache, lengths, active):
+                    cache = cache._replace(length=lengths)
+                    return model.decode_step(params, tok, cache, shard,
+                                             valid=active.astype(jnp.int32))
+            else:
+                def extend_fn(params, toks, cache, lengths, valid, active):
+                    cache = cache._replace(length=lengths)
+                    logits, new = extend_raw(params, toks, cache, valid)
+                    return logits, self._select(active, new, cache)
+
+                def decode_fn(params, tok, cache, lengths, active):
+                    cache = cache._replace(length=lengths)
+                    logits, new = model.decode_step(params, tok, cache,
+                                                    shard)
+                    return logits, self._select(active, new, cache)
 
             def zero_fn(cache, admit):
                 zeros = jax.tree.map(jnp.zeros_like, cache)

@@ -51,8 +51,17 @@ class _FakeCache(NamedTuple):
     length: jax.Array        # [] or [B]
 
 
+def _frozen(buf, new, start, valid):
+    """``new`` where ``valid > 0``; elsewhere the rows of ``buf`` that
+    ``new`` would overwrite at ``start`` — the models' freeze contract."""
+    old = jax.vmap(lambda b, s: jax.lax.dynamic_slice_in_dim(
+        b, s, new.shape[1], axis=0))(buf, start)
+    return jnp.where((valid > 0)[:, None], new, old)
+
+
 class FakeLM:
-    """Deterministic LM: logits are one-hot at ``(7*tok + 3) % vocab``."""
+    """Deterministic LM: logits are one-hot at ``(7*tok + 3) % vocab``.
+    A slot with ``valid == 0`` keeps its history, as in the real models."""
 
     vocab = VOCAB
 
@@ -72,11 +81,13 @@ class FakeLM:
         upd = jax.vmap(lambda buf, new, start:
                        jax.lax.dynamic_update_slice_in_dim(buf, new, start,
                                                            axis=0))
-        newtoks = upd(cache.toks, toks, length)
+        rows = toks if valid is None else _frozen(cache.toks, toks, length,
+                                                  valid)
+        newtoks = upd(cache.toks, rows, length)
         adv = C if valid is None else valid
         return self._logits(toks), _FakeCache(newtoks, cache.length + adv)
 
-    def decode_step(self, params, tok, cache, shard=None):
+    def decode_step(self, params, tok, cache, shard=None, valid=None):
         B = tok.shape[0]
         length = cache.length
         if jnp.ndim(length) == 0:
@@ -84,8 +95,12 @@ class FakeLM:
         upd = jax.vmap(lambda buf, new, start:
                        jax.lax.dynamic_update_slice_in_dim(buf, new, start,
                                                            axis=0))
-        newtoks = upd(cache.toks, tok[:, None], length)
-        return self._logits(tok), _FakeCache(newtoks, cache.length + 1)
+        rows = tok[:, None]
+        if valid is not None:
+            rows = _frozen(cache.toks, rows, length, valid)
+        newtoks = upd(cache.toks, rows, length)
+        adv = 1 if valid is None else valid
+        return self._logits(tok), _FakeCache(newtoks, cache.length + adv)
 
 
 def fake_engine(**kw) -> ServeEngine:
@@ -378,6 +393,61 @@ def test_per_slot_decode_matches_scalar(tiny_lm):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                atol=0.08, rtol=0)
+
+
+def test_native_path_freezes_inactive_slots_in_the_write(tiny_lm):
+    """On the native bf16 path the model's KV write freezes inactive
+    slots: their K/V come back bit-identical in every layer, and the live
+    slots' K/V and logits equal the whole-cache select's (the same call
+    with ``valid=None``, then ``jnp.where`` over the cache)."""
+    model, params, _ = tiny_lm
+    B, C = 3, 8
+    eng = ServeEngine(model, params, batch_size=B, max_len=24,
+                      prefill_chunk=C)
+    kk, kv = jax.random.split(jax.random.key(7))
+    shape = eng.cache.layers.k.shape
+    layers = eng.cache.layers._replace(
+        k=jax.random.normal(kk, shape).astype(jnp.bfloat16),
+        v=jax.random.normal(kv, shape).astype(jnp.bfloat16))
+    cache = eng.cache._replace(layers=layers)
+    assert not bool(jnp.any(layers.k == 0))
+    lengths = jnp.array([5, 9, 3], jnp.int32)
+
+    def whole_select(active, new):
+        m = active[None, :, None, None, None]
+        return (jnp.where(m, new.layers.k, layers.k),
+                jnp.where(m, new.layers.v, layers.v))
+
+    def check(active, logits, new, ref_logits, ref):
+        for got, old, want in zip((new.layers.k, new.layers.v),
+                                  (layers.k, layers.v),
+                                  whole_select(active, ref)):
+            np.testing.assert_array_equal(np.asarray(got[:, ~active]),
+                                          np.asarray(old[:, ~active]))
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(logits[active]),
+                                      np.asarray(ref_logits[active]))
+
+    toks = jax.random.randint(jax.random.key(8), (B, C), 0, 256)
+    valid = jnp.array([C, 0, C], jnp.int32)
+    active = np.asarray(valid) > 0
+    logits, new = eng._extend_fn(params, toks, cache, lengths, valid,
+                                 jnp.asarray(active))
+    ref_logits, ref = model.extend(params, toks,
+                                   cache._replace(length=lengths))
+    check(active, logits, new, ref_logits, ref)
+    np.testing.assert_array_equal(np.asarray(new.length),
+                                  np.asarray(lengths + valid))
+
+    tok = jnp.array([11, 22, 33], jnp.int32)
+    active = np.array([True, True, False])
+    logits, new = eng._decode_fn(params, tok, cache, lengths,
+                                 jnp.asarray(active))
+    ref_logits, ref = model.decode_step(params, tok,
+                                        cache._replace(length=lengths))
+    check(active, logits, new, ref_logits, ref)
+    np.testing.assert_array_equal(np.asarray(new.length),
+                                  np.asarray(lengths + active))
 
 
 def test_engine_vs_tensorized_model(tiny_lm):

@@ -503,12 +503,14 @@ def test_train_step_hlo_names_its_layers():
         assert _has_scope(names, scope), scope
 
 
-def test_engine_programs_name_the_select():
+def _engine_programs(arch: str):
+    """A smoke-size engine of ``arch`` and its compiled extend and decode
+    programs."""
     from repro.configs import base as cfgbase
     from repro.launch import steps
     from repro.serving.engine import ServeEngine
 
-    model, _ = steps.build_model(cfgbase.get("internlm2_1_8b"), smoke=True)
+    model, _ = steps.build_model(cfgbase.get(arch), smoke=True)
     eng = ServeEngine(model, model.init(jax.random.key(0)), batch_size=2,
                       max_len=32, prefill_chunk=4)
     vec = jnp.zeros(2, jnp.int32)
@@ -517,7 +519,46 @@ def test_engine_programs_name_the_select():
                                eng._state(), vec, vec, off).compile()
     dec = eng._decode_fn.lower(eng.params, vec, eng._state(), vec,
                                off).compile()
-    for compiled in (ext, dec):
+    return eng, (ext, dec)
+
+
+def test_engine_programs_name_the_select():
+    _, programs = _engine_programs("internlm2_1_8b")
+    for compiled in programs:
         names = _op_names(compiled)
         assert _has_scope(names, "engine_select")
         assert _has_scope(names, "attention")
+
+
+def _largest_in_scope(compiled, scope) -> int:
+    """Elements of the largest output of an instruction in ``scope``."""
+    import math
+    import re
+    most = 0
+    for line in compiled.as_text().splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        if not m or not _has_scope([m.group(1)], scope) or " = " not in line:
+            continue
+        rest = line.split(" = ", 1)[1]
+        typ = re.match(r"(.*?)\s[a-z][\w\-.]*\(", rest).group(1)
+        for dims in re.findall(r"\[([\d,]*)\]", typ):
+            most = max(most, math.prod(int(d) for d in dims.split(",") if d))
+    return most
+
+
+@pytest.mark.parametrize("arch,whole", [("internlm2_1_8b", False),
+                                        ("rwkv6_7b", True)])
+def test_engine_select_spans_written_rows_or_whole_state(arch, whole):
+    """On the native bf16 path the ``engine_select`` scope holds only the
+    chunk-sized freeze of the rows written, smaller than one layer's
+    ``[B, cache_len, KV, hd]`` cache; the SSM fallback still selects over
+    its whole state."""
+    eng, programs = _engine_programs(arch)
+    if whole:
+        leaf = max(a.size for a in jax.tree.leaves(eng._state()))
+    else:
+        leaf = eng._state().layers.k[0].size
+    for compiled in programs:
+        largest = _largest_in_scope(compiled, "engine_select")
+        assert largest > 0
+        assert (largest >= leaf) if whole else (largest < leaf)
