@@ -56,7 +56,7 @@ def run(print_fn=print) -> list[dict]:
     q = jax.random.normal(jax.random.key(0), (bh, t, dk)) * 0.5
     k2 = jax.random.normal(jax.random.key(1), (bh, t, dk)) * 0.5
     v = jax.random.normal(jax.random.key(2), (bh, t, dv)) * 0.5
-    ld = -jnp.ones((bh, t, dk)) * 0.05
+    ld = -jnp.ones((bh, t)) * 0.05       # ssd mode: one decay per token
     us_chunk = _time(jax.jit(
         lambda *args: ref.chunked_linear_scan(*args, chunk=128)),
         q, k2, v, ld) * 1e6

@@ -19,7 +19,7 @@ from repro.core.tensorized import TNNConfig
 ARCH_IDS = [
     "rwkv6_7b", "qwen3_moe_235b_a22b", "olmoe_1b_7b", "llava_next_34b",
     "seamless_m4t_medium", "internlm2_1_8b", "phi4_mini_3_8b",
-    "tinyllama_1_1b", "qwen2_7b", "zamba2_7b",
+    "tinyllama_1_1b", "qwen2_7b", "zamba2_7b", "granite_4_0_h_micro",
 ]
 
 PAPER_IDS = ["paper_atis_tt"]   # UCF LSTM layers live in benchmarks/workloads.py

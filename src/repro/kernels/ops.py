@@ -81,10 +81,16 @@ def linear_scan(q: jax.Array, k: jax.Array, v: jax.Array,
                 use_pallas: bool | None = None) -> tuple[jax.Array, jax.Array]:
     """Chunked linear recurrence over [BH, T, d*] streams (ssd / rwkv6).
 
+    ``log_decay`` is [BH, T] in ``ssd`` mode (Mamba-2: one decay per stream
+    and token) and [BH, T, dk] in ``rwkv6`` mode (one per channel).
     Returns (o: [BH, T, dv], final_state: [BH, dk, dv] f32).  Differentiable
     (custom VJP through the chunked-jnp twin).  ``use_pallas=None`` picks
     the Pallas kernel on TPU and the identical chunked-jnp twin elsewhere
     (interpret-mode grid loops distort compile-time cost analysis)."""
+    decay_shape = q.shape[:2] if mode == "ssd" else q.shape
+    if log_decay.shape != decay_shape:
+        raise ValueError(f"{mode} mode takes a log-decay of shape "
+                         f"{decay_shape}, got {log_decay.shape}")
     if use_pallas is None:
         use_pallas = USE_PALLAS_DEFAULT
     if u is None:

@@ -44,10 +44,13 @@ def linear_scan(q: jax.Array, k: jax.Array, v: jax.Array,
     mode="ssd":   a = d, g = 1   (Mamba-2:  o_t = q_t S_t)
     mode="rwkv6": a = 1, g = u   (bonus on the current token)
 
-    Shapes: q, k, log_decay: [T, dk]; v: [T, dv]; u: [dk].
+    Shapes: q, k: [T, dk]; v: [T, dv]; u: [dk]; log_decay: [T] in ``ssd``
+    mode (one decay per token), [T, dk] in ``rwkv6`` mode.
     """
     assert mode in ("ssd", "rwkv6")
     dk, dv = k.shape[-1], v.shape[-1]
+    if mode == "ssd":
+        log_decay = log_decay[:, None]             # [T, 1]: every row alike
     d = jnp.exp(log_decay.astype(jnp.float32))
     if u is None:
         u = jnp.zeros((dk,), jnp.float32)
@@ -102,8 +105,10 @@ def chunked_linear_scan(q, k, v, log_decay, u=None, *, mode="ssd",
     def blocks(z, d):
         return jnp.moveaxis(z.astype(f32).reshape(bh, nc, c, d), 1, 0)
 
+    if mode == "ssd":
+        log_decay = log_decay[..., None]           # [BH, T, 1]
     qb, kb, vb, ldb = (blocks(q, dk), blocks(k, dk), blocks(v, dv),
-                       blocks(log_decay, dk))
+                       blocks(log_decay, log_decay.shape[-1]))
     row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
     tri = (row >= col) if mode == "ssd" else (row > col)
@@ -113,10 +118,14 @@ def chunked_linear_scan(q, k, v, log_decay, u=None, *, mode="ssd",
         lc = jnp.cumsum(ldc, axis=1)
         ex = lc if mode == "ssd" else lc - ldc
         qt = qc * jnp.exp(ex)
-        kt = kc * jnp.exp(-lc)
-        att = jnp.einsum("bik,bjk->bij", qt, kt)
-        att = jnp.where(tri[None], att, 0.0)
-        if mode == "rwkv6":
+        if mode == "ssd":
+            # lc: [BH, C, 1]; the ratio exp(lc_i - lc_j) as such
+            seg = lc[:, :, None, 0] - lc[:, None, :, 0]            # [BH,C,C]
+            ratio = jnp.exp(jnp.where(tri[None], seg, -jnp.inf))
+            att = jnp.einsum("bik,bjk->bij", qc, kc) * ratio
+        else:
+            att = jnp.einsum("bik,bjk->bij", qt, kc * jnp.exp(-lc))
+            att = jnp.where(tri[None], att, 0.0)
             diag = jnp.sum(qc * u[:, None, :] * kc, axis=-1)   # [BH, C]
             att = att + jax.vmap(jnp.diag)(diag)
         o = jnp.einsum("bij,bjv->biv", att, vc) + jnp.einsum(

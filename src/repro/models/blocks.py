@@ -180,6 +180,8 @@ class Attention:
     head_dim: int
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    use_rope: bool = True            # False: no position embedding (NoPE)
+    softmax_scale: float | None = None   # None: 1 / sqrt(head_dim)
     causal: bool = True
     q_chunk: int = 512               # blockwise attention tile sizes
     kv_chunk: int = 1024
@@ -219,9 +221,15 @@ class Attention:
             params["k"], x).reshape(B, T, KV, D)
         v = self._proj(self.d_model, KV * D, self.qkv_bias, "qkv")(
             params["v"], x).reshape(B, T, KV, D)
-        q = rope(q, positions, self.rope_theta)
-        k = rope(k, positions, self.rope_theta)
+        if self.use_rope:
+            q = rope(q, positions, self.rope_theta)
+            k = rope(k, positions, self.rope_theta)
         return q, k, v
+
+    def _scaled(self, scores):
+        if self.softmax_scale is None:
+            return scores / math.sqrt(self.head_dim)
+        return scores * self.softmax_scale
 
     def _out(self, params, ctx):
         B, T = ctx.shape[:2]
@@ -240,6 +248,7 @@ class Attention:
             ctx = blockwise_attention(q, k, v, causal=self.causal,
                                       q_chunk=self.q_chunk,
                                       kv_chunk=self.kv_chunk,
+                                      softmax_scale=self.softmax_scale,
                                       mesh=getattr(shard, "mesh", None))
             return self._out(params, ctx)
 
@@ -248,6 +257,7 @@ class Attention:
         q, k, v = self._qkv(params, x, positions)
         ctx = blockwise_attention(q, k, v, causal=self.causal,
                                   q_chunk=self.q_chunk, kv_chunk=self.kv_chunk,
+                                  softmax_scale=self.softmax_scale,
                                   mesh=getattr(shard, "mesh", None))
         B, T, KV, D = k.shape
         pad = max_len - T
@@ -302,8 +312,9 @@ class Attention:
 
             groups = H // KV
             qg = q.reshape(B, 1, KV, groups, D)
-            scores = jnp.einsum("bqkgd,btkd->bkgqt", qg.astype(jnp.float32),
-                                kc.astype(jnp.float32)) / math.sqrt(D)
+            scores = self._scaled(jnp.einsum(
+                "bqkgd,btkd->bkgqt", qg.astype(jnp.float32),
+                kc.astype(jnp.float32)))
             t_idx = jnp.arange(kc.shape[1])
             if per_slot:
                 mask = (t_idx[None, None, None, None, :]
@@ -359,8 +370,9 @@ class Attention:
 
             groups = H // KV
             qg = q.reshape(B, C, KV, groups, D)
-            scores = jnp.einsum("bckgd,btkd->bkgct", qg.astype(jnp.float32),
-                                kc.astype(jnp.float32)) / math.sqrt(D)
+            scores = self._scaled(jnp.einsum(
+                "bckgd,btkd->bkgct", qg.astype(jnp.float32),
+                kc.astype(jnp.float32)))
             t_idx = jnp.arange(kc.shape[1])
             mask = (t_idx[None, None, None, None, :]
                     <= positions[:, None, None, :, None])

@@ -3,12 +3,16 @@
 One `LM` class instantiates dense-attention (tinyllama/qwen2/phi4/internlm2/
 llava backbone), MoE (qwen3-moe, olmoe), attention-free (rwkv6), and hybrid
 (zamba2: Mamba-2 backbone + a parameter-shared attention block every k
-layers) families from an :class:`LMConfig`.
+layers; granite-4.0-h: Mamba-2 and attention layers in one stack by a
+published pattern, each with its own weights and MLP) families from an
+:class:`LMConfig`.
 
 Structure notes:
 * Homogeneous layer stacks are ``lax.scan``-ned over stacked params (HLO is
   O(1 layer) — the 94-layer MoE compiles in minutes on the dry-run host),
-  with optional ``jax.checkpoint`` per layer (activation remat).
+  with optional ``jax.checkpoint`` per layer (activation remat).  A mixed
+  stack (``mixer_period``) stores each run of consecutive same-kind layers
+  as its own stack, in the published order, and scans each run.
 * Inputs are token ids (``int``) or precomputed embeddings (``float`` —
   the VLM/audio modality-frontend stubs feed these).
 * Three execution paths: ``__call__`` (teacher-forced training),
@@ -22,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 from typing import Any, NamedTuple
 
 import jax
@@ -65,10 +70,19 @@ class LMConfig:
     moe: MoESpec | None = None
     hybrid: HybridSpec | None = None
     ssm_state: int = 64
+    ssm_chunk: int = 128                   # Mamba-2 SSD chunk
+    mixer_period: int = 0                  # > 0 (block mamba2): attention at
+    mixer_offset: int = 0                  #   i % mixer_period == mixer_offset
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    position_embedding: str = "rope"       # rope | nope
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
+    # Granite's scalings; the defaults are the identity
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float | None = None   # softmax scale; None: 1/sqrt(hd)
+    logits_scaling: float = 1.0
     tnn: TNNConfig = TNNConfig()
     q_chunk: int = 512
     kv_chunk: int = 1024
@@ -83,8 +97,31 @@ class LMConfig:
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
 
+    @property
+    def layer_types(self) -> tuple[str, ...]:
+        """Each layer's mixer, "attention" or "mamba", in order."""
+        if not self.mixer_period:
+            return (("mamba",) if self.block == "mamba2"
+                    else ("attention",)) * self.num_layers
+        return tuple("attention" if i % self.mixer_period == self.mixer_offset
+                     else "mamba" for i in range(self.num_layers))
+
+    @property
+    def runs(self) -> tuple[tuple[str, int], ...]:
+        """The layer types as runs of one kind: ``(kind, count)``."""
+        out: list[list] = []
+        for kind in self.layer_types:
+            if out and out[-1][0] == kind:
+                out[-1][1] += 1
+            else:
+                out.append([kind, 1])
+        return tuple((k, n) for k, n in out)
+
     def validate(self):
         assert self.block in ("attn", "rwkv6", "mamba2")
+        assert self.position_embedding in ("rope", "nope")
+        if self.mixer_period:
+            assert self.block == "mamba2" and not self.hybrid and not self.moe
         if self.hybrid:
             assert self.block == "mamba2", "hybrid = mamba2 backbone"
             assert self.num_layers % self.hybrid.shared_every == 0, (
@@ -145,8 +182,18 @@ class LM:
             self.rwkv = ssm.RWKV6Block(c.d_model, head_dim=c.hd, d_ff=c.d_ff,
                                        tnn=tnn, **common)
         elif c.block == "mamba2":
-            self.mamba = ssm.Mamba2Block(c.d_model, d_state=c.ssm_state,
-                                         head_dim=c.hd, tnn=tnn, **common)
+            self.mamba = ssm.Mamba2Block(
+                c.d_model, d_state=c.ssm_state,
+                head_dim=c.hd, chunk=c.ssm_chunk,
+                norm_eps=c.norm_eps, tnn=tnn, **common)
+            if c.mixer_period:
+                self.attn = Attention(
+                    c.d_model, c.num_heads, c.num_kv_heads, c.hd,
+                    qkv_bias=c.qkv_bias, rope_theta=c.rope_theta,
+                    use_rope=c.position_embedding == "rope",
+                    softmax_scale=c.attention_multiplier, q_chunk=c.q_chunk,
+                    kv_chunk=c.kv_chunk, tnn=tnn, **common)
+                self.mlp = SwiGLU(c.d_model, c.d_ff, tnn=tnn, **common)
             if c.hybrid:
                 self.shared_attn = Attention(
                     c.d_model, c.num_heads, c.num_kv_heads, c.hd,
@@ -157,6 +204,16 @@ class LM:
                     **common)
 
     # -- init -----------------------------------------------------------------
+
+    def _mixed_layer_init(self, kind: str, key: jax.Array) -> dict:
+        c = self.cfg
+        k1, k2 = jax.random.split(key)
+        mixer = (("attn", self.attn) if kind == "attention"
+                 else ("mamba", self.mamba))
+        return {"ln1": rmsnorm_init(c.d_model),
+                mixer[0]: mixer[1].init(k1),
+                "ln2": rmsnorm_init(c.d_model),
+                "mlp": self.mlp.init(k2)}
 
     def _layer_init(self, key: jax.Array) -> dict:
         c = self.cfg
@@ -177,12 +234,20 @@ class LM:
         c = self.cfg
         ke, kl, kh, ko = jax.random.split(key, 4)
         std = 1.0 / math.sqrt(c.d_model)
+        if c.mixer_period:
+            keys = jax.random.split(kl, len(c.runs))
+            layers = tuple(
+                jax.vmap(partial(self._mixed_layer_init, kind))(
+                    jax.random.split(k, n))
+                for (kind, n), k in zip(c.runs, keys))
+        else:
+            layers = jax.vmap(self._layer_init)(
+                jax.random.split(kl, c.num_layers))
         params = {
             "embed": (jax.random.normal(ke, (c.vocab, c.d_model), jnp.float32)
                       * std).astype(c.param_dtype),
             "ln_f": rmsnorm_init(c.d_model),
-            "layers": jax.vmap(self._layer_init)(
-                jax.random.split(kl, c.num_layers)),
+            "layers": layers,
         }
         if not c.tie_embeddings:
             params["lm_head"] = Dense(
@@ -208,6 +273,8 @@ class LM:
             x = jnp.take(table, inputs, axis=0)
         else:
             x = inputs.astype(c.compute_dtype)   # modality stub embeddings
+        if c.embedding_multiplier != 1.0:
+            x = x * c.embedding_multiplier
         return shard(x, ("batch", "seq", None))
 
     def _logits(self, params, x):
@@ -215,9 +282,13 @@ class LM:
         with jax.named_scope("lm_head"):
             if c.tie_embeddings:
                 w = params["embed"].astype(c.compute_dtype)
-                return einsum_f32("btd,vd->btv", x, w).astype(c.compute_dtype)
-            return Dense(c.d_model, c.vocab, param_dtype=c.param_dtype,
-                         compute_dtype=c.compute_dtype)(params["lm_head"], x)
+                y = einsum_f32("btd,vd->btv", x, w)
+                if c.logits_scaling != 1.0:
+                    y = y / c.logits_scaling
+                return y.astype(c.compute_dtype)
+            y = Dense(c.d_model, c.vocab, param_dtype=c.param_dtype,
+                      compute_dtype=c.compute_dtype)(params["lm_head"], x)
+            return y / c.logits_scaling if c.logits_scaling != 1.0 else y
 
     def _moe_apply(self, lp_mlp, y, shard):
         """Group tokens by batch row (groups shard over `data`)."""
@@ -272,6 +343,59 @@ class LM:
                                 shard)
         return x
 
+    def _residual(self, h):
+        m = self.cfg.residual_multiplier
+        return h * m if m != 1.0 else h
+
+    def _mixed_layer(self, lp, x, positions, shard, max_len=None):
+        """One layer of a mixed stack: its mixer (attention or Mamba-2, by
+        the key its weights are under), then its own MLP, each added on a
+        scaled residual.  With ``max_len`` (prefill) the second value is
+        the layer's decode state: its KV cache or its Mamba-2 state."""
+        c = self.cfg
+        h = rmsnorm(lp["ln1"], x, c.norm_eps)
+        state = {}
+        if "attn" in lp and max_len is None:
+            m = self.attn(lp["attn"], h, positions, shard)
+        elif "attn" in lp:
+            m, state = self.attn.prefill(lp["attn"], h, positions, max_len,
+                                         shard)
+        elif max_len is None:
+            m = self.mamba(lp["mamba"], h, shard)
+        else:
+            m, state = self.mamba(lp["mamba"], h, shard, return_state=True)
+        x = x + self._residual(m)
+        y = self.mlp(lp["mlp"], rmsnorm(lp["ln2"], x, c.norm_eps), shard)
+        return shard(x + self._residual(y), ("batch", "seq", None)), state
+
+    def _mixed_decode(self, lp, x, state, pos, shard):
+        """One token through one layer of a mixed stack; ``state`` is the
+        layer's KV cache (its length is bookkeeping, ``pos`` is read) or
+        its Mamba-2 state."""
+        c = self.cfg
+        h = rmsnorm(lp["ln1"], x, c.norm_eps)
+        if "attn" in lp:
+            m, kv = self.attn.decode_step(
+                lp["attn"], h, KVCache(state.k, state.v, pos), shard)
+            state = KVCache(kv.k, kv.v, state.length + 1)
+        else:
+            m, state = self.mamba.decode_step(lp["mamba"], h, state)
+        x = x + self._residual(m)
+        y = self.mlp(lp["mlp"], rmsnorm(lp["ln2"], x, c.norm_eps), shard)
+        return x + self._residual(y), state
+
+    def _scan_runs(self, step, x, layers, *per_run):
+        """``step`` scanned over each run of a mixed stack in order, with
+        each run's entry of ``per_run`` scanned beside its weights;
+        returns x and the runs' stacked outputs."""
+        outs = []
+        for run, *extra in zip(layers, *per_run):
+            n = jax.tree.leaves(run)[0].shape[0]
+            x, y = _maybe_scan(step, x, (run, *extra), self.cfg.scan_layers,
+                               n)
+            outs.append(y)
+        return x, tuple(outs)
+
     # -- full-sequence forward (training) --------------------------------------
 
     def apply_layers(self, layers: dict, x: jax.Array, positions: jax.Array,
@@ -284,12 +408,19 @@ class LM:
         scan/remat/remat-group lowering is identical either way, so a
         partitioned stack computes the same per-layer values as the
         monolithic forward.  Hybrid (shared-block) stacks interleave
-        non-stack params and stay in :meth:`__call__`.
+        non-stack params and stay in :meth:`__call__`; a mixed stack (a
+        tuple of runs) runs run by run.
         """
         c = self.cfg
+        if c.mixer_period and isinstance(layers, tuple):
+            for run in layers:
+                x, _ = self.apply_layers(run, x, positions, shard)
+            return x, {}
         n = jax.tree.leaves(layers)[0].shape[0]
 
         def layer_fn(x, lp):
+            if c.mixer_period:
+                return self._mixed_layer(lp, x, positions, shard)
             if c.block == "attn":
                 return self._attn_layer(lp, x, positions, shard)
             if c.block == "rwkv6":
@@ -405,12 +536,21 @@ class LM:
         c = self.cfg
         L = c.num_layers
 
-        def stack(state):
+        def stack(state, n=L):
             return jax.tree.map(
-                lambda s: jnp.zeros((L,) + s.shape, s.dtype), state)
+                lambda s: jnp.zeros((n,) + s.shape, s.dtype), state)
 
         shared = None
-        if c.block == "attn":
+        if c.mixer_period:
+            def run_cache(kind, n):
+                if kind == "attention":
+                    shape = (n, batch, max_len, c.num_kv_heads, c.hd)
+                    return KVCache(k=jnp.zeros(shape, c.compute_dtype),
+                                   v=jnp.zeros(shape, c.compute_dtype),
+                                   length=jnp.zeros((n,), jnp.int32))
+                return stack(self.mamba.init_state(batch), n)
+            layers = tuple(run_cache(kind, n) for kind, n in c.runs)
+        elif c.block == "attn":
             layers = KVCache(
                 k=jnp.zeros((L, batch, max_len, c.num_kv_heads, c.hd),
                             c.compute_dtype),
@@ -453,7 +593,13 @@ class LM:
         pos = cache.length
         new_shared = None
 
-        if c.block == "attn":
+        if c.mixer_period:
+            def step(x, scan_in):
+                lp, st = scan_in
+                return self._mixed_decode(lp, x, st, pos, shard)
+            x, new_layers = self._scan_runs(step, x, params["layers"],
+                                            cache.layers)
+        elif c.block == "attn":
             def step(x, scan_in):
                 lp, kv = scan_in
                 lkv = KVCache(kv.k, kv.v, pos)
@@ -601,7 +747,12 @@ class LM:
         x = self._embed(params, inputs, shard)
         new_shared = None
 
-        if c.block == "attn":
+        if c.mixer_period:
+            def step(x, scan_in):
+                return self._mixed_layer(scan_in[0], x, positions, shard,
+                                         max_len=max_len)
+            x, new_layers = self._scan_runs(step, x, params["layers"])
+        elif c.block == "attn":
             def step(x, lp):
                 h, kv = self.attn.prefill(
                     lp["attn"], rmsnorm(lp["ln1"], x, c.norm_eps), positions,

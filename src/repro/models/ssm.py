@@ -12,7 +12,9 @@ Both token mixers reduce to the chunked linear recurrence implemented in
   those four is an accuracy refinement orthogonal to the compute pattern;
   decay keeps the full data-dependent path.  (Documented simplification.)
 * Mamba-2: SSD with scalar-per-head decay exp(a·dt), shared B/C across
-  heads (MQA-like), depthwise causal conv on x/B/C, gated output.
+  heads (MQA-like, one group), depthwise causal conv on x/B/C, and the
+  gated RMSNorm of the published mixer: ``y * silu(z)`` normalized over
+  d_inner, then scaled.
 
 Both blocks expose train (full-sequence, chunked kernel) and decode
 (single-step recurrence on a carried state) paths.
@@ -27,6 +29,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry as tm
 from repro.kernels import ops
 from repro.models.blocks import Dense, Shard, groupnorm_heads, no_shard
 
@@ -219,6 +222,8 @@ class Mamba2Block:
     head_dim: int = 64
     expand: int = 2
     conv_width: int = 4
+    chunk: int = 128                  # SSD chunk (the published chunk size)
+    norm_eps: float = 1e-5            # the gated RMSNorm's epsilon
     tnn: TNNConfig | None = None
     param_dtype: jnp.dtype = jnp.float32
     compute_dtype: jnp.dtype = jnp.bfloat16
@@ -272,7 +277,14 @@ class Mamba2Block:
         out = sum(up[:, i:i + u.shape[1]] * w[i] for i in range(self.conv_width))
         return jax.nn.silu(out + params["conv_b"]).astype(u.dtype)
 
-    def _ssd(self, params, xs, Bm, Cm, dt, chunk, use_pallas=None):
+    def _gated_norm(self, params, y, z):
+        """RMSNorm of ``y * silu(z)`` over d_inner, then the weight."""
+        g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        var = jnp.mean(g * g, axis=-1, keepdims=True)
+        return (g * jax.lax.rsqrt(var + self.norm_eps)
+                * params["norm"]).astype(z.dtype)
+
+    def _ssd(self, params, xs, Bm, Cm, dt, use_pallas=None):
         B_, T = xs.shape[:2]
         H, hd, S = self.num_heads, self.head_dim, self.d_state
         dt = jax.nn.softplus(dt.astype(jnp.float32)
@@ -287,41 +299,42 @@ class Mamba2Block:
         k = stream(Bm, S) * dt.transpose(0, 2, 1).reshape(B_ * H, T, 1)
         q = stream(Cm, S)
         v = xh.transpose(0, 2, 1, 3).reshape(B_ * H, T, hd)
-        ldk = jnp.broadcast_to(
-            ld.transpose(0, 2, 1)[..., None], (B_, H, T, S)
-        ).reshape(B_ * H, T, S)
-        if T % chunk != 0:
-            chunk = math.gcd(T, chunk) or 1
-        y, state = ops.linear_scan(q.astype(self.compute_dtype),
-                                   k.astype(self.compute_dtype),
-                                   v.astype(self.compute_dtype),
-                                   ldk, mode="ssd", chunk=min(chunk, T),
-                                   use_pallas=use_pallas)      # [B*H, T, hd]
+        ld = ld.transpose(0, 2, 1).reshape(B_ * H, T)         # [B*H, T]
+        chunk = math.gcd(T, self.chunk)
+        if use_pallas is None:
+            use_pallas = ops.USE_PALLAS_DEFAULT
+        tm.inc("ssd.calls." + ("pallas" if use_pallas else "jnp"))
+        tm.inc(f"ssd.chunk.{chunk}")
+        with jax.named_scope("ssd"):
+            y, state = ops.linear_scan(q.astype(self.compute_dtype),
+                                       k.astype(self.compute_dtype),
+                                       v.astype(self.compute_dtype),
+                                       ld, mode="ssd", chunk=chunk,
+                                       use_pallas=use_pallas)  # [B*H, T, hd]
         y = y.reshape(B_, H, T, hd).transpose(0, 2, 1, 3)      # [B, T, H, hd]
         y = y + xh * params["D_skip"][None, None, :, None]
         return y.reshape(B_, T, self.d_inner), state.reshape(B_, H, S, hd)
 
     def __call__(self, params: dict, x: jax.Array, shard: Shard = no_shard,
-                 chunk: int = 128, use_pallas: bool | None = None,
-                 return_state: bool = False):
-        B, T, D = x.shape
-        z, xs, Bm, Cm, dt = self._split(params, x)
-        conv_in = jnp.concatenate([xs, Bm, Cm], axis=-1)
-        conv_out = self._conv_train(params, conv_in)
-        xs, Bm, Cm = jnp.split(conv_out, [self.d_inner, self.d_inner
-                                          + self.d_state], axis=-1)
-        y, ssm_state = self._ssd(params, xs, Bm, Cm, dt, chunk, use_pallas)
-        y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
-        y = (y.astype(jnp.float32) * params["norm"]).astype(x.dtype)
-        out = self._proj(self.d_inner, D, target="out")(params["out"], y)
-        if return_state:
-            w = self.conv_width - 1
-            tail = conv_in[:, -w:].astype(jnp.float32)
-            pad = jnp.zeros((B, max(0, w - T), self.conv_dim), jnp.float32)
-            state = MambaState(ssm=ssm_state,
-                               conv=jnp.concatenate([pad, tail], axis=1))
-            return out, state
-        return out
+                 use_pallas: bool | None = None, return_state: bool = False):
+        with jax.named_scope("mamba"):
+            B, T, D = x.shape
+            z, xs, Bm, Cm, dt = self._split(params, x)
+            conv_in = jnp.concatenate([xs, Bm, Cm], axis=-1)
+            conv_out = self._conv_train(params, conv_in)
+            xs, Bm, Cm = jnp.split(conv_out, [self.d_inner, self.d_inner
+                                              + self.d_state], axis=-1)
+            y, ssm_state = self._ssd(params, xs, Bm, Cm, dt, use_pallas)
+            y = self._gated_norm(params, y, z)
+            out = self._proj(self.d_inner, D, target="out")(params["out"], y)
+            if return_state:
+                w = self.conv_width - 1
+                tail = conv_in[:, -w:].astype(jnp.float32)
+                pad = jnp.zeros((B, max(0, w - T), self.conv_dim), jnp.float32)
+                state = MambaState(ssm=ssm_state,
+                                   conv=jnp.concatenate([pad, tail], axis=1))
+                return out, state
+            return out
 
     # -- decode ----------------------------------------------------------------
 
@@ -336,32 +349,31 @@ class Mamba2Block:
     def decode_step(self, params: dict, x: jax.Array, state: MambaState
                     ) -> tuple[jax.Array, MambaState]:
         """x: [B, 1, D]."""
-        B = x.shape[0]
-        H, hd, S = self.num_heads, self.head_dim, self.d_state
-        z, xs, Bm, Cm, dt = self._split(params, x)
-        u = jnp.concatenate([xs, Bm, Cm], axis=-1)[:, 0]       # [B, conv_dim]
-        window = jnp.concatenate([state.conv, u[:, None].astype(jnp.float32)],
-                                 axis=1)                        # [B, W, C]
-        w = params["conv_w"].astype(jnp.float32)
-        conv_out = jax.nn.silu(jnp.sum(window * w[None], axis=1)
-                               + params["conv_b"])              # [B, C]
-        xs, Bm, Cm = (conv_out[:, :self.d_inner],
-                      conv_out[:, self.d_inner:self.d_inner + S],
-                      conv_out[:, self.d_inner + S:])
-        dtv = jax.nn.softplus(dt[:, 0].astype(jnp.float32)
-                              + params["dt_bias"])              # [B, H]
-        decay = jnp.exp(dtv * -jnp.exp(params["A_log"]))        # [B, H]
-        xh = xs.reshape(B, H, hd).astype(jnp.float32)
-        kv = jnp.einsum("bs,bhp->bhsp", Bm.astype(jnp.float32), xh)
-        new_ssm = (state.ssm * decay[..., None, None]
-                   + kv * dtv[..., None, None])
-        y = jnp.einsum("bs,bhsp->bhp", Cm.astype(jnp.float32), new_ssm)
-        y = y + xh * params["D_skip"][None, :, None]
-        y = y.reshape(B, 1, self.d_inner).astype(x.dtype)
-        y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
-        y = (y.astype(jnp.float32) * params["norm"]).astype(x.dtype)
-        out = self._proj(self.d_inner, self.d_model, target="out")(
-            params["out"], y)
-        new_state = MambaState(ssm=new_ssm,
-                               conv=window[:, 1:].astype(jnp.float32))
-        return out, new_state
+        with jax.named_scope("mamba"):
+            B = x.shape[0]
+            H, hd, S = self.num_heads, self.head_dim, self.d_state
+            z, xs, Bm, Cm, dt = self._split(params, x)
+            u = jnp.concatenate([xs, Bm, Cm], axis=-1)[:, 0]       # [B, conv_dim]
+            window = jnp.concatenate([state.conv, u[:, None].astype(jnp.float32)],
+                                     axis=1)                        # [B, W, C]
+            w = params["conv_w"].astype(jnp.float32)
+            conv_out = jax.nn.silu(jnp.sum(window * w[None], axis=1)
+                                   + params["conv_b"])              # [B, C]
+            xs, Bm, Cm = (conv_out[:, :self.d_inner],
+                          conv_out[:, self.d_inner:self.d_inner + S],
+                          conv_out[:, self.d_inner + S:])
+            dtv = jax.nn.softplus(dt[:, 0].astype(jnp.float32)
+                                  + params["dt_bias"])              # [B, H]
+            decay = jnp.exp(dtv * -jnp.exp(params["A_log"]))        # [B, H]
+            xh = xs.reshape(B, H, hd).astype(jnp.float32)
+            kv = jnp.einsum("bs,bhp->bhsp", Bm.astype(jnp.float32), xh)
+            new_ssm = (state.ssm * decay[..., None, None]
+                       + kv * dtv[..., None, None])
+            y = jnp.einsum("bs,bhsp->bhp", Cm.astype(jnp.float32), new_ssm)
+            y = y + xh * params["D_skip"][None, :, None]
+            y = self._gated_norm(params, y.reshape(B, 1, self.d_inner), z)
+            out = self._proj(self.d_inner, self.d_model, target="out")(
+                params["out"], y)
+            new_state = MambaState(ssm=new_ssm,
+                                   conv=window[:, 1:].astype(jnp.float32))
+            return out, new_state

@@ -151,6 +151,38 @@ def test_flash_attention_tinyllama(one_chip):
     )
 
 
+def test_flash_attention_granite_head_64(one_chip):
+    """Granite-4.0-H's attention: 32 heads of 64 over 8 KV heads, batch 2 x
+    4096, its configured softmax scale."""
+    q, kv = (2, 4096, 32, 64), (2, 4096, 8, 64)
+    _compile(
+        lambda q, k, v: flash_attention_fwd(
+            q, k, v, softmax_scale=0.015625, interpret=False),
+        one_chip,
+        (q, BF16),
+        (kv, BF16),
+        (kv, BF16),
+    )
+
+
+def test_ssd_scan_granite_shapes(one_chip):
+    """Granite-4.0-H's Mamba-2 scan: 2 x 64 streams of 4096 tokens, state
+    128 x 64, the published chunk of 256."""
+    bh, t, dk, dv = 2 * 64, 4096, 128, 64
+
+    def fn(q, k, v, ld):
+        return linear_scan_pallas(q, k, v, ld, mode="ssd", chunk=256, interpret=False)
+
+    _compile(
+        fn,
+        one_chip,
+        ((bh, t, dk), BF16),
+        ((bh, t, dk), BF16),
+        ((bh, t, dv), BF16),
+        ((bh, t), F32),
+    )
+
+
 @pytest.mark.parametrize("mode", ["ssd", "rwkv6"])
 def test_linear_scan_real_width(one_chip, mode):
     bh, t, d = 8 * 64, 2048, 64  # rwkv6-7b: 64 heads of 64
@@ -159,7 +191,8 @@ def test_linear_scan_real_width(one_chip, mode):
         return linear_scan_pallas(q, k, v, ld, u, mode=mode, interpret=False)
 
     seq = ((bh, t, d), BF16)
-    _compile(fn, one_chip, seq, seq, seq, ((bh, t, d), F32), ((bh, d), F32))
+    decay = ((bh, t), F32) if mode == "ssd" else ((bh, t, d), F32)
+    _compile(fn, one_chip, seq, seq, seq, decay, ((bh, d), F32))
 
 
 @pytest.mark.parametrize(

@@ -55,7 +55,9 @@ def test_linear_scan(mode, t, chunk, dtype):
     q = (jax.random.normal(key, (bh, t, dk), F32) * 0.5).astype(dtype)
     k = (jax.random.normal(jax.random.key(1), (bh, t, dk), F32) * 0.5).astype(dtype)
     v = (jax.random.normal(jax.random.key(2), (bh, t, dv), F32) * 0.5).astype(dtype)
-    ld = -jnp.exp(jax.random.normal(jax.random.key(3), (bh, t, dk), F32)) * 0.1
+    # ssd (Mamba-2): one decay per stream and token; rwkv6: one per channel
+    decay_shape = (bh, t) if mode == "ssd" else (bh, t, dk)
+    ld = -jnp.exp(jax.random.normal(jax.random.key(3), decay_shape, F32)) * 0.1
     u = jax.random.normal(jax.random.key(4), (bh, dk), F32) * 0.5
     got, got_state = ops.linear_scan(
         q, k, v, ld, u, mode=mode, chunk=chunk, use_pallas=True
@@ -75,13 +77,45 @@ def test_linear_scan(mode, t, chunk, dtype):
     )
 
 
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_linear_scan_ssd_strong_decay(use_pallas):
+    """Mamba-2 decays that sum to about -250 over a chunk, where
+    exp(-cumsum) overflows float32: the chunked scan stays finite and is
+    the recurrence."""
+    bh, t, dk, dv = 2, 256, 16, 16
+    q = jax.random.normal(jax.random.key(0), (bh, t, dk)) * 0.5
+    k = jax.random.normal(jax.random.key(1), (bh, t, dk)) * 0.5
+    v = jax.random.normal(jax.random.key(2), (bh, t, dv)) * 0.5
+    ld = -4.0 * jax.random.uniform(jax.random.key(3), (bh, t))
+    got, got_state = ops.linear_scan(
+        q, k, v, ld, mode="ssd", chunk=128, use_pallas=use_pallas
+    )
+    want, want_state = ref.linear_scan_batched(q, k, v, ld, mode="ssd")
+    assert np.all(np.isfinite(np.asarray(got)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(
+        np.asarray(got_state), np.asarray(want_state), rtol=1e-4, atol=1e-4
+    )
+
+
+@pytest.mark.parametrize("mode", ["ssd", "rwkv6"])
+def test_linear_scan_rejects_the_other_modes_decay(mode):
+    """ssd mode takes one decay per stream and token ([BH, T]), rwkv6 one
+    per channel ([BH, T, dk]); each refuses the other's shape."""
+    bh, t, dk = 2, 64, 16
+    x = jnp.zeros((bh, t, dk))
+    wrong = jnp.zeros((bh, t, dk) if mode == "ssd" else (bh, t))
+    with pytest.raises(ValueError, match="log-decay"):
+        ops.linear_scan(x, x, x, wrong, mode=mode, chunk=64, use_pallas=False)
+
+
 def test_linear_scan_state_continuity():
     """Chunk boundaries must be invisible: chunk=64 == chunk=128 results."""
     bh, t, dk, dv = 2, 256, 16, 16
     q = jax.random.normal(jax.random.key(0), (bh, t, dk)) * 0.5
     k = jax.random.normal(jax.random.key(1), (bh, t, dk)) * 0.5
     v = jax.random.normal(jax.random.key(2), (bh, t, dv)) * 0.5
-    ld = -jnp.ones((bh, t, dk)) * 0.05
+    ld = -jnp.ones((bh, t)) * 0.05
     a, sa = ops.linear_scan(q, k, v, ld, mode="ssd", chunk=64, use_pallas=True)
     b, sb = ops.linear_scan(q, k, v, ld, mode="ssd", chunk=128, use_pallas=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
