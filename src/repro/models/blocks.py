@@ -25,6 +25,7 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.core.tensorized import TNNConfig, TensorizedLinear, make_tensorized_linear
 
@@ -155,21 +156,47 @@ class KVCache(NamedTuple):
 
 
 def _keep_frozen(buf: jax.Array, new: jax.Array, start: jax.Array,
-                 valid: jax.Array) -> jax.Array:
-    """The rows to write into ``buf`` ([B, T, ...]) at each slot's
-    ``start`` ([B]): ``new`` ([B, C, ...]) where ``valid > 0``, and where
-    ``valid == 0`` the C rows already there, so that the write leaves a
-    frozen slot exactly as it was.  Reads only the rows to be written,
-    never the whole buffer."""
+                 valid: jax.Array, layer=None) -> jax.Array:
+    """The rows to write into ``buf`` ([B, T, ...], or with ``layer`` the
+    stacked [L, B, T, ...]) at each slot's ``start`` ([B]): ``new``
+    ([B, C, ...]) where ``valid > 0``, and where ``valid == 0`` the C rows
+    already there, so that the write leaves a frozen slot exactly as it
+    was.  Reads only the rows to be written, never the whole buffer."""
     with jax.named_scope("engine_select"):
         # One dynamic_slice per slot: a vmapped one lowers to a gather,
         # for which the TPU compiler re-lays out the whole buffer.
-        size = (1, new.shape[1]) + buf.shape[2:]
+        lead = () if layer is None else (layer,)
+        rows = (1, new.shape[1]) + buf.shape[len(lead) + 2:]
+        size = (1,) * len(lead) + rows
+        zeros = (0,) * (len(rows) - 2)
         old = jnp.concatenate([jax.lax.dynamic_slice(
-            buf, (b, start[b]) + (0,) * (buf.ndim - 2), size)
-            for b in range(buf.shape[0])])
+            buf, lead + (b, start[b]) + zeros, size).reshape(rows)
+            for b in range(new.shape[0])])
         keep = (valid > 0).reshape((-1,) + (1,) * (new.ndim - 1))
         return jnp.where(keep, new.astype(buf.dtype), old)
+
+
+def _write_rows(buf: jax.Array, new: jax.Array, start: jax.Array,
+                layer) -> tuple[jax.Array, jax.Array]:
+    """Write ``new`` ([B, C, ...]) into the stacked cache ``buf`` ([L, B,
+    T, ...]) at (``layer``, slot, ``start``), in place: one
+    dynamic_update_slice per slot for a per-slot ``start`` ([B]; a vmapped
+    one lowers to a scatter), one for all slots for a scalar ``start``.
+    Returns the stack and its layer ``layer`` as attention reads it."""
+    new = new.astype(buf.dtype)
+    zeros = (0,) * (buf.ndim - 3)
+    if jnp.ndim(start) == 0:
+        buf = jax.lax.dynamic_update_slice(buf, new[None],
+                                           (layer, 0, start) + zeros)
+    else:
+        for b in range(new.shape[0]):
+            buf = jax.lax.dynamic_update_slice(
+                buf, new[b][None, None], (layer, b, start[b]) + zeros)
+    # Hold the stack in the layout it is passed in.  Left free, the TPU
+    # compiler lays the loop's stack out for extend's score product, and
+    # so copies the whole cache into and out of that layout on each call.
+    buf = with_layout_constraint(buf, Layout(tuple(range(buf.ndim))))
+    return buf, jax.lax.dynamic_index_in_dim(buf, layer, keepdims=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -269,7 +296,7 @@ class Attention:
         return self._out(params, ctx), cache
 
     def decode_step(self, params, x, cache: KVCache, shard: Shard = no_shard,
-                    valid: jax.Array | None = None):
+                    valid: jax.Array | None = None, layer=None):
         """One-token decode.  x: [B, 1, d_model].
 
         ``cache.length`` is a scalar (every slot at the same depth — the
@@ -282,7 +309,12 @@ class Attention:
         slots with 0: their write at ``length`` puts back the row already
         there, and their length does not advance.  Only that one row per
         slot is read and selected, never the whole buffer; a frozen slot's
-        logits are don't-care."""
+        logits are don't-care.
+
+        With ``layer`` (an index into the stack), ``cache.k``/``cache.v``
+        are the whole stacked ``[L, B, T, KV, hd]`` buffers: only the new
+        rows are written into them, attention reads layer ``layer``, and
+        the returned cache holds the stacks."""
         with jax.named_scope("attention"):
             B = x.shape[0]
             H, KV, D = self._shapes
@@ -294,20 +326,23 @@ class Attention:
                 positions = jnp.broadcast_to(length, (B, 1))
             q, k, v = self._qkv(params, x, positions)
             if valid is not None:
-                k = _keep_frozen(cache.k, k, positions[:, 0], valid)
-                v = _keep_frozen(cache.v, v, positions[:, 0], valid)
-            if per_slot:
+                k = _keep_frozen(cache.k, k, positions[:, 0], valid, layer)
+                v = _keep_frozen(cache.v, v, positions[:, 0], valid, layer)
+            if layer is not None:
+                ks, kc = _write_rows(cache.k, k, length, layer)
+                vs, vc = _write_rows(cache.v, v, length, layer)
+            elif per_slot:
                 upd = jax.vmap(
                     lambda buf, new, start: jax.lax.dynamic_update_slice_in_dim(
                         buf, new, start, axis=0))
-                kc = upd(cache.k, k, length)
-                vc = upd(cache.v, v, length)
+                kc = ks = upd(cache.k, k, length)
+                vc = vs = upd(cache.v, v, length)
             else:
-                kc = jax.lax.dynamic_update_slice_in_dim(cache.k, k, length,
-                                                         axis=1)
-                vc = jax.lax.dynamic_update_slice_in_dim(cache.v, v, length,
-                                                         axis=1)
-            new_cache = KVCache(kc, vc, length + (1 if valid is None
+                kc = ks = jax.lax.dynamic_update_slice_in_dim(
+                    cache.k, k, length, axis=1)
+                vc = vs = jax.lax.dynamic_update_slice_in_dim(
+                    cache.v, v, length, axis=1)
+            new_cache = KVCache(ks, vs, length + (1 if valid is None
                                                   else valid))
 
             groups = H // KV
@@ -329,7 +364,7 @@ class Attention:
             return self._out(params, ctx), new_cache
 
     def extend(self, params, x, cache: KVCache, shard: Shard = no_shard,
-               valid: jax.Array | None = None):
+               valid: jax.Array | None = None, layer=None):
         """Chunked prefill: append a C-token chunk per slot at each slot's
         current cache depth.  x: [B, C, d_model]; ``cache.length`` scalar
         or per-slot [B].
@@ -345,7 +380,8 @@ class Attention:
         back for every chunk position ([B, C, ...]); the caller reads row
         ``valid-1`` of slots whose prompt completed — in-chunk queries
         past valid, and every query of a frozen slot, produce don't-care
-        rows."""
+        rows.  ``layer`` is as in :meth:`decode_step`: a stacked cache
+        written in place."""
         with jax.named_scope("attention"):
             B, C, _ = x.shape
             H, KV, D = self._shapes
@@ -358,15 +394,19 @@ class Attention:
                 keep = (jnp.arange(C)[None, :] < valid[:, None])[..., None, None]
                 k = jnp.where(keep, k, jnp.zeros((), k.dtype))
                 v = jnp.where(keep, v, jnp.zeros((), v.dtype))
-                k = _keep_frozen(cache.k, k, length, valid)
-                v = _keep_frozen(cache.v, v, length, valid)
-            upd = jax.vmap(
-                lambda buf, new, start: jax.lax.dynamic_update_slice_in_dim(
-                    buf, new.astype(buf.dtype), start, axis=0))
-            kc = upd(cache.k, k, length)
-            vc = upd(cache.v, v, length)
+                k = _keep_frozen(cache.k, k, length, valid, layer)
+                v = _keep_frozen(cache.v, v, length, valid, layer)
+            if layer is not None:
+                ks, kc = _write_rows(cache.k, k, length, layer)
+                vs, vc = _write_rows(cache.v, v, length, layer)
+            else:
+                upd = jax.vmap(
+                    lambda buf, new, start: jax.lax.dynamic_update_slice_in_dim(
+                        buf, new.astype(buf.dtype), start, axis=0))
+                kc = ks = upd(cache.k, k, length)
+                vc = vs = upd(cache.v, v, length)
             adv = C if valid is None else valid
-            new_cache = KVCache(kc, vc, cache.length + adv)
+            new_cache = KVCache(ks, vs, cache.length + adv)
 
             groups = H // KV
             qg = q.reshape(B, C, KV, groups, D)

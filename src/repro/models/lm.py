@@ -31,6 +31,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.tensorized import TNNConfig
 from repro.models import ssm
@@ -155,6 +156,16 @@ def _maybe_scan(step, x, xs, use_scan, n):
     if use_scan:
         return jax.lax.scan(step, x, xs)
     return _unrolled_scan(step, x, xs, n)
+
+
+def _layer_loop(step, carry, layers, use_scan, n):
+    """``carry = step(carry, (layer_params, i))`` over the n layers, with
+    no per-layer outputs: state such as the stacked KV cache rides in the
+    carry and is updated in place.  ``i`` is static when unrolled."""
+    carry, _ = _maybe_scan(lambda c, a: (step(c, a), None), carry,
+                           (layers, np.arange(n, dtype=np.int32)), use_scan,
+                           n)
+    return carry
 
 
 # ---------------------------------------------------------------------------
@@ -582,7 +593,9 @@ class LM:
         ``valid`` ([B] int32 of 0 or 1, None = every slot; attention blocks
         only) freezes the slots with 0 inside each layer's KV write, as
         :meth:`Attention.decode_step` says: their k/v and length come back
-        as they went in."""
+        as they went in.  An attention stack carries its stacked K/V
+        through the layer loop and writes only the new rows, so a caller
+        that donates the cache has it updated in place."""
         c = self.cfg
         if valid is not None and c.block != "attn":
             raise ValueError("decode_step(valid=...) requires an "
@@ -600,29 +613,23 @@ class LM:
             x, new_layers = self._scan_runs(step, x, params["layers"],
                                             cache.layers)
         elif c.block == "attn":
-            def step(x, scan_in):
-                lp, kv = scan_in
-                lkv = KVCache(kv.k, kv.v, pos)
+            def step(carry, scan_in):
+                x, k, v = carry
+                lp, i = scan_in
                 h, new_kv = self.attn.decode_step(
-                    lp["attn"], rmsnorm(lp["ln1"], x, c.norm_eps), lkv, shard,
-                    valid=valid)
+                    lp["attn"], rmsnorm(lp["ln1"], x, c.norm_eps),
+                    KVCache(k, v, pos), shard, valid=valid, layer=i)
                 x = x + h
                 y = rmsnorm(lp["ln2"], x, c.norm_eps)
                 if c.moe:
                     ym, _ = self._moe_apply(lp["mlp"], y, shard)
                 else:
                     ym = self.mlp(lp["mlp"], y, shard)
-                return x + ym, KVCache(new_kv.k, new_kv.v,
-                                       jnp.zeros((), jnp.int32))
-            if c.scan_layers:
-                x, new_layers = jax.lax.scan(step, x, (params["layers"],
-                                                       cache.layers))
-            else:
-                x, new_layers = _unrolled_scan(step, x, (params["layers"],
-                                                         cache.layers),
-                                               c.num_layers)
-            new_layers = KVCache(new_layers.k, new_layers.v,
-                                 cache.layers.length + 1)
+                return x + ym, new_kv.k, new_kv.v
+            x, ks, vs = _layer_loop(
+                step, (x, cache.layers.k, cache.layers.v), params["layers"],
+                c.scan_layers, c.num_layers)
+            new_layers = KVCache(ks, vs, cache.layers.length + 1)
         elif c.block == "rwkv6":
             def step(x, scan_in):
                 lp, st = scan_in
@@ -710,22 +717,22 @@ class LM:
         x = self._embed(params, tokens, shard)
         pos = cache.length
 
-        def step(x, scan_in):
-            lp, kv = scan_in
-            lkv = KVCache(kv.k, kv.v, pos)
+        def step(carry, scan_in):
+            x, k, v = carry
+            lp, i = scan_in
             h, new_kv = self.attn.extend(
-                lp["attn"], rmsnorm(lp["ln1"], x, c.norm_eps), lkv, shard,
-                valid=valid)
+                lp["attn"], rmsnorm(lp["ln1"], x, c.norm_eps),
+                KVCache(k, v, pos), shard, valid=valid, layer=i)
             x = x + h
             y = rmsnorm(lp["ln2"], x, c.norm_eps)
             if c.moe:
                 ym, _ = self._moe_apply(lp["mlp"], y, shard)
             else:
                 ym = self.mlp(lp["mlp"], y, shard)
-            return x + ym, (new_kv.k, new_kv.v)
+            return x + ym, new_kv.k, new_kv.v
 
-        x, (ks, vs) = _maybe_scan(step, x, (params["layers"], cache.layers),
-                                  c.scan_layers, c.num_layers)
+        x, ks, vs = _layer_loop(step, (x, cache.layers.k, cache.layers.v),
+                                params["layers"], c.scan_layers, c.num_layers)
         # Per-layer lengths are bookkeeping only (decode/extend read the
         # global cache.length); advance by the chunk width.
         new_layers = KVCache(ks, vs, cache.layers.length + C)

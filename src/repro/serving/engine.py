@@ -29,17 +29,22 @@ its neighbours are doing (no left-padding, no cross-slot contamination
 arrays are the authoritative slot state; the device cache's ``length``
 is overwritten from them before every call.
 
-Inactive slots are frozen out of every call, so a garbage write from a
-padded lane can never corrupt a live slot's state.  With a native
-``extend`` and a bf16 cache, the model does it inside its own KV write:
-``extend`` and ``decode_step`` take ``valid`` and put back a frozen
-slot's rows where they would write (a chunk's rows per slot and layer,
-never the whole cache).  Models without a native ``extend`` (SSM/hybrid
-blocks) prefill through a sequential fallback, a ``lax.scan`` of
-``decode_step`` over chunk columns, and they and the quantized cache
-freeze by a per-leaf batch-axis select over the whole state (SSM states
-have no rows to mask, and the quantized path must keep padding out of
-its monotone amax); so does the slot-zeroing at admission.
+Every tick program takes the device state donated and returns its
+successor, which the engine keeps in place of what it passed.  With a
+native ``extend`` and a bf16 cache, the model writes the new rows in
+place into the donated cache (the stacked K/V ride the layer loop's
+carry; only each call's rows per slot and layer are written), and it
+freezes inactive slots inside that write, so a garbage write from a
+padded lane can never corrupt a live slot's state: ``extend`` and
+``decode_step`` take ``valid`` and put back a frozen slot's rows where
+they would write, never selecting over the whole cache.  Models without
+a native ``extend`` (SSM/hybrid blocks) prefill through a sequential
+fallback, a ``lax.scan`` of ``decode_step`` over chunk columns, and they
+and the quantized cache freeze by a per-leaf batch-axis select over the
+whole state (SSM states have no rows to mask, and the quantized path
+must keep padding out of its monotone amax); so does the slot-zeroing at
+admission.  The counters ``serve.kv_inplace`` and ``serve.kv_whole``
+count the calls of each kind.
 """
 
 from __future__ import annotations
@@ -177,8 +182,9 @@ class ServeEngine:
         """Per-leaf batch-axis select over the whole state: inactive slots
         keep their old state.  It serves the sequential fallback and the
         slot-zeroing at admission; the quantized cache selects in
-        ``requant``, and the native bf16 path freezes inside the model's
-        KV write instead.  Axis rule: every stacked per-layer buffer in
+        ``requant``, and the native bf16 path writes its rows in place
+        into the donated cache and freezes inside that write instead.
+        Axis rule: every stacked per-layer buffer in
         this repo is >= 3-D with batch on axis 1 ([L, B, ...]), per-slot
         vectors are 1-/2-D with batch on axis 0 — checked in that order,
         so the rule stays correct when num_layers happens to equal
@@ -277,9 +283,14 @@ class ServeEngine:
 
             zero_fn = None
 
-        self._extend_fn = jax.jit(extend_fn)
-        self._decode_fn = jax.jit(decode_fn)
-        self._zero_fn = jax.jit(zero_fn) if zero_fn is not None else None
+        # The state is donated: each call's result replaces it
+        # (``_set_state``), and nothing reads a state once passed.
+        self._extend_fn = jax.jit(extend_fn, donate_argnums=2)
+        self._decode_fn = jax.jit(decode_fn, donate_argnums=2)
+        self._zero_fn = (jax.jit(zero_fn, donate_argnums=0)
+                         if zero_fn is not None else None)
+        self._kv_counter = ("serve.kv_inplace" if self._native_extend
+                            and policy is None else "serve.kv_whole")
 
     def _state(self):
         return self.cache if self.kv_policy is None else self.qkv
@@ -324,15 +335,16 @@ class ServeEngine:
         zl = jnp.zeros(B, jnp.int32)
         toks = jnp.zeros((B, C), jnp.int32)
         act = jnp.zeros(B, bool)
-        logits, _ = self._extend_fn(self.params, toks, self._state(), zl, zl,
-                                    act)
+        # Each call donates its state: thread the result into the next.
+        logits, state = self._extend_fn(self.params, toks, self._state(), zl,
+                                        zl, act)
         last = logits[jnp.arange(B), zl]
         self._sample(last, np.zeros(B, np.float32))
-        dlogits, _ = self._decode_fn(self.params, jnp.zeros(B, jnp.int32),
-                                     self._state(), zl, act)
+        dlogits, state = self._decode_fn(self.params, jnp.zeros(B, jnp.int32),
+                                         state, zl, act)
         self._sample(dlogits, np.zeros(B, np.float32))
         if self._zero_fn is not None:
-            self._zero_fn(self._state(), jnp.zeros(B, bool))
+            self._zero_fn(state, jnp.zeros(B, bool))
         self.key = key0
         self._init_device_cache()
 
@@ -360,6 +372,7 @@ class ServeEngine:
             mask = np.zeros(self.batch, bool)
             mask[admitted] = True
             self._set_state(self._zero_fn(self._state(), jnp.asarray(mask)))
+            tm.inc("serve.kv_whole")
         self.max_occupancy = max(self.max_occupancy, self.occupancy)
         if admitted:
             tm.inc("serve.admitted", len(admitted))
@@ -457,6 +470,7 @@ class ServeEngine:
                 jnp.asarray(self.lengths.copy()), jnp.asarray(valid),
                 jnp.asarray(active))
             self._set_state(state)
+            tm.inc(self._kv_counter)
             self.lengths[active] += valid[active]
             self.prefill_pos[active] += valid[active]
 
@@ -487,6 +501,7 @@ class ServeEngine:
                 self._state(), jnp.asarray(self.lengths.copy()),
                 jnp.asarray(active))
             self._set_state(state)
+            tm.inc(self._kv_counter)
             self.lengths[active] += 1
             temps = np.array([self.slot_req[s].temperature if active[s]
                               else 0.0 for s in range(self.batch)],

@@ -217,3 +217,50 @@ def test_chain_vmem_guard_matches_compiler(one_chip, m0, shapes):
     else:
         with pytest.raises(fc.ChainLoweringError, match="VMEM budget"):
             _compile(fn, one_chip, *args)
+
+
+@pytest.mark.parametrize("phase", ["extend", "decode"])
+def test_engine_tick_writes_the_cache_in_place(one_chip, phase):
+    """The serving engine's tick programs at InternLM2's attention widths
+    and the chat cell's cache (16 slots of 2080 rows; two layers): the
+    donated cache is aliased to the output, and no instruction copies a
+    whole stacked K or V, as one that lays the loop's stack out anew for
+    extend's score product would."""
+    import re
+
+    from repro.models.lm import LM, LMConfig
+    from repro.serving.engine import ServeEngine
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    class AbstractEngine(ServeEngine):
+        def _init_device_cache(self):
+            cache = jax.eval_shape(lambda: self.model.init_cache(
+                self.batch, self.cache_len))
+            self.cache = jax.tree.map(spec, cache._replace(
+                length=jax.ShapeDtypeStruct((self.batch,), jnp.int32)))
+            self.qkv = None
+
+    cfg = LMConfig(name="chat-attn", num_layers=2, d_model=2048,
+                   num_heads=16, num_kv_heads=8, head_dim=128, d_ff=256,
+                   vocab=256, remat=False)
+    model = LM(cfg)
+    params = jax.tree.map(spec, jax.eval_shape(model.init, jax.random.key(0)))
+    B, C = 16, 32
+    eng = AbstractEngine(model, params, batch_size=B, max_len=2048,
+                         prefill_chunk=C)
+    vec = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+    if phase == "extend":
+        toks = jax.ShapeDtypeStruct((B, C), jnp.int32, sharding=one_chip)
+        lowered = eng._extend_fn.lower(params, toks, eng.cache, vec, vec, mask)
+    else:
+        lowered = eng._decode_fn.lower(params, vec, eng.cache, vec, mask)
+    compiled = lowered.compile()
+    stack = eng.cache.layers.k
+    stack_bytes = stack.size * stack.dtype.itemsize
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * stack_bytes
+    dims = ",".join(map(str, stack.shape))
+    copies = re.findall(rf"= bf16\[{dims}\]\S* copy\(", compiled.as_text())
+    assert not copies

@@ -399,7 +399,8 @@ def test_native_path_freezes_inactive_slots_in_the_write(tiny_lm):
     """On the native bf16 path the model's KV write freezes inactive
     slots: their K/V come back bit-identical in every layer, and the live
     slots' K/V and logits equal the whole-cache select's (the same call
-    with ``valid=None``, then ``jnp.where`` over the cache)."""
+    with ``valid=None``, then ``jnp.where`` over the cache).  The tick
+    programs donate the cache, so each call gets its own copy."""
     model, params, _ = tiny_lm
     B, C = 3, 8
     eng = ServeEngine(model, params, batch_size=B, max_len=24,
@@ -431,8 +432,8 @@ def test_native_path_freezes_inactive_slots_in_the_write(tiny_lm):
     toks = jax.random.randint(jax.random.key(8), (B, C), 0, 256)
     valid = jnp.array([C, 0, C], jnp.int32)
     active = np.asarray(valid) > 0
-    logits, new = eng._extend_fn(params, toks, cache, lengths, valid,
-                                 jnp.asarray(active))
+    logits, new = eng._extend_fn(params, toks, jax.tree.map(jnp.copy, cache),
+                                 lengths, valid, jnp.asarray(active))
     ref_logits, ref = model.extend(params, toks,
                                    cache._replace(length=lengths))
     check(active, logits, new, ref_logits, ref)
@@ -441,13 +442,56 @@ def test_native_path_freezes_inactive_slots_in_the_write(tiny_lm):
 
     tok = jnp.array([11, 22, 33], jnp.int32)
     active = np.array([True, True, False])
-    logits, new = eng._decode_fn(params, tok, cache, lengths,
-                                 jnp.asarray(active))
+    logits, new = eng._decode_fn(params, tok, jax.tree.map(jnp.copy, cache),
+                                 lengths, jnp.asarray(active))
     ref_logits, ref = model.decode_step(params, tok,
                                         cache._replace(length=lengths))
     check(active, logits, new, ref_logits, ref)
     np.testing.assert_array_equal(np.asarray(new.length),
                                   np.asarray(lengths + active))
+
+
+def test_donated_engine_serves_soundly(tiny_lm):
+    """The tick programs donate the state they are given.  After
+    ``warmup()`` an engine admits, prefills, decodes and re-admits into a
+    freed slot with the greedy outputs of a second engine run on the same
+    requests; ``serve.kv_inplace`` counts each native extend and decode
+    call, and ``serve.kv_whole`` only the admitting ticks' slot-zeroing."""
+    from repro import telemetry as tm
+    model, params, _ = tiny_lm
+    rng = np.random.default_rng(5)
+    prompts = _prompts(rng, 3, lo=5, hi=14)
+    budgets = (3, 7, 4)
+
+    def serve(warm):
+        eng = ServeEngine(model, params, batch_size=2, max_len=32,
+                          prefill_chunk=4)
+        if warm:
+            eng.warmup()
+        calls = []
+        ext, dec = eng._extend_fn, eng._decode_fn
+        eng._extend_fn = lambda *a: calls.append("extend") or ext(*a)
+        eng._decode_fn = lambda *a: calls.append("decode") or dec(*a)
+        for rid, (p, n) in enumerate(zip(prompts, budgets)):
+            eng.submit(mk_req(rid, p, max_new=n))
+        tm.reset()
+        tm.configure()
+        try:
+            done = {r.rid: r.out_tokens for r in eng.run()}
+            counters = tm.counters()
+        finally:
+            tm.reset()
+        return eng, done, calls, counters
+
+    eng, got, calls, counters = serve(warm=True)
+    _, want, _, _ = serve(warm=False)
+    assert got == want
+    assert [len(got[r]) for r in range(3)] == list(budgets)
+    admits = [t for t, kind, _ in eng.events if kind == "admit"]
+    assert len(set(admits)) >= 2        # the third request re-admits
+    assert {"extend", "decode"} <= set(calls)
+    assert counters["serve.kv_inplace"] == len(calls)
+    assert counters["serve.kv_whole"] == len(set(admits))
 
 
 def test_engine_vs_tensorized_model(tiny_lm):

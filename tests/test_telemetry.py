@@ -503,8 +503,8 @@ def test_train_step_hlo_names_its_layers():
         assert _has_scope(names, scope), scope
 
 
-def _engine_programs(arch: str):
-    """A smoke-size engine of ``arch`` and its compiled extend and decode
+def _engine_lowered(arch: str):
+    """A smoke-size engine of ``arch`` and its lowered extend and decode
     programs."""
     from repro.configs import base as cfgbase
     from repro.launch import steps
@@ -516,10 +516,43 @@ def _engine_programs(arch: str):
     vec = jnp.zeros(2, jnp.int32)
     off = jnp.zeros(2, bool)
     ext = eng._extend_fn.lower(eng.params, jnp.zeros((2, 4), jnp.int32),
-                               eng._state(), vec, vec, off).compile()
-    dec = eng._decode_fn.lower(eng.params, vec, eng._state(), vec,
-                               off).compile()
+                               eng._state(), vec, vec, off)
+    dec = eng._decode_fn.lower(eng.params, vec, eng._state(), vec, off)
     return eng, (ext, dec)
+
+
+def _engine_programs(arch: str):
+    """A smoke-size engine of ``arch`` and its compiled extend and decode
+    programs."""
+    eng, lowered = _engine_lowered(arch)
+    return eng, tuple(low.compile() for low in lowered)
+
+
+def test_engine_programs_update_the_cache_in_place():
+    """The native bf16 tick programs write the cache in place: (a) every
+    cache leaf they take is donated and aliased to an output, and (b) no
+    dynamic_update_slice writes a block as large as one layer's
+    ``[B, cache_len, KV, hd]`` cache, which is what stacking each layer's
+    whole K and V back as a scan output does."""
+    import math
+    import re
+    eng, lowered = _engine_lowered("internlm2_1_8b")
+    layer = eng._state().layers.k[0].size
+    for low in lowered:
+        text = low.compile().as_text()
+        head = text.splitlines()[0]
+        aliased = {int(n) for n in re.findall(r"\(\s*(\d+),\s*\{\}", head)}
+        cache = {int(m.group(1)) for m in re.finditer(
+            r'parameter\((\d+)\).*op_name="cache\.', text)}
+        assert len(cache) >= 2
+        assert cache <= aliased, (cache, aliased)
+
+        updates = [m.group(1) for m in re.finditer(
+            r"stablehlo\.dynamic_update_slice .*?: \(tensor<[^>]*>, "
+            r"tensor<([^>]*)>", low.as_text())]
+        assert updates
+        for dims in updates:
+            assert math.prod(int(d) for d in dims.split("x")[:-1]) < layer
 
 
 def test_engine_programs_name_the_select():
