@@ -44,27 +44,12 @@ class EncDecConfig:
     q_chunk: int = 512
     kv_chunk: int = 1024
     remat: bool = True
-    scan_layers: bool = True
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.bfloat16
 
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
-
-
-def _maybe_scan(step, x, xs, use_scan, n):
-    if use_scan:
-        return jax.lax.scan(step, x, xs)
-    ys = []
-    for i in range(n):
-        x, y = step(x, jax.tree.map(lambda p: p[i], xs))
-        ys.append(y)
-    stacked = jax.tree.map(lambda *a: jnp.stack(a), *ys) if ys[0] is not None         else None
-    return x, stacked
-
-
-_maybe_scan2 = _maybe_scan
 
 
 class EncDecCache(NamedTuple):
@@ -161,8 +146,7 @@ class EncDec:
         if c.remat:
             layer_fn = jax.checkpoint(
                 layer_fn, policy=jax.checkpoint_policies.nothing_saveable)
-        x, _ = _maybe_scan(layer_fn, x, params["enc_layers"], c.scan_layers,
-                           c.num_enc_layers)
+        x, _ = jax.lax.scan(layer_fn, x, params["enc_layers"])
         return rmsnorm(params["ln_enc"], x, c.norm_eps)
 
     # -- decoder (teacher-forced) --------------------------------------------
@@ -190,8 +174,7 @@ class EncDec:
         if c.remat:
             layer_fn = jax.checkpoint(
                 layer_fn, policy=jax.checkpoint_policies.nothing_saveable)
-        x, _ = _maybe_scan(layer_fn, x, params["dec_layers"], c.scan_layers,
-                           c.num_dec_layers)
+        x, _ = jax.lax.scan(layer_fn, x, params["dec_layers"])
         x = rmsnorm(params["ln_f"], x, c.norm_eps)
         logits = Dense(c.d_model, c.vocab, param_dtype=c.param_dtype,
                        compute_dtype=c.compute_dtype)(params["lm_head"], x)
@@ -234,8 +217,7 @@ class EncDec:
                              shard)
             return x, (kv.k, kv.v)
 
-        x, (ks, vs) = _maybe_scan(step, x, params["dec_layers"],
-                                  c.scan_layers, c.num_dec_layers)
+        x, (ks, vs) = jax.lax.scan(step, x, params["dec_layers"])
         x = rmsnorm(params["ln_f"], x, c.norm_eps)
         logits = Dense(c.d_model, c.vocab, param_dtype=c.param_dtype,
                        compute_dtype=c.compute_dtype)(params["lm_head"],
@@ -267,9 +249,8 @@ class EncDec:
                              shard)
             return x, (new_kv.k, new_kv.v)
 
-        x, (ks, vs) = _maybe_scan2(step, x, (params["dec_layers"],
-                                              cache.self_kv),
-                                   c.scan_layers, c.num_dec_layers)
+        x, (ks, vs) = jax.lax.scan(step, x, (params["dec_layers"],
+                                             cache.self_kv))
         x = rmsnorm(params["ln_f"], x, c.norm_eps)
         logits = Dense(c.d_model, c.vocab, param_dtype=c.param_dtype,
                        compute_dtype=c.compute_dtype)(params["lm_head"], x)[:, 0]

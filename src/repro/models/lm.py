@@ -8,16 +8,27 @@ published pattern, each with its own weights and MLP) families from an
 :class:`LMConfig`.
 
 Structure notes:
-* Homogeneous layer stacks are ``lax.scan``-ned over stacked params (HLO is
+* A layer is one of three kinds: attention, Mamba-2 or RWKV-6.  Each kind
+  has one body (``_attention``, ``_mamba``, ``_rwkv``) that serves every
+  phase: norm → the mixer's call for the phase → residual, then, in layers
+  that have one, norm → MLP (RWKV: its channel mix) → residual.  The phase
+  (:class:`_Phase`) decides only which mixer method is called and what
+  state comes back.
+* One walker (``_walk``) runs the stack.  Each run of consecutive
+  same-kind layers is ``lax.scan``-ned over its stacked params (HLO is
   O(1 layer) — the 94-layer MoE compiles in minutes on the dry-run host),
-  with optional ``jax.checkpoint`` per layer (activation remat).  A mixed
-  stack (``mixer_period``) stores each run of consecutive same-kind layers
-  as its own stack, in the published order, and scans each run.
+  with optional ``jax.checkpoint`` per layer in training.  A mixed stack
+  (``mixer_period``) stores each run as its own stack, in the published
+  order; Zamba2's shared block is the attention body applied to
+  ``params["shared"]`` after each group.  In decode and extend, attention
+  runs carry their stacked K/V through the layer loop and write only the
+  new rows, and state runs freeze a slot's state with a ``where``.
 * Inputs are token ids (``int``) or precomputed embeddings (``float`` —
   the VLM/audio modality-frontend stubs feed these).
-* Three execution paths: ``__call__`` (teacher-forced training),
+* Four execution paths: ``__call__`` (teacher-forced training),
   ``prefill`` (chunked-kernel prompt ingestion returning decode state),
-  ``decode_step`` (one token).
+  ``decode_step`` (one token) and ``extend`` (a chunk of tokens at each
+  slot's depth: the serving engine's prefill tick).
 * The paper's technique enters through ``cfg.tnn`` — every projection
   consults it (see ``blocks.Dense``).
 """
@@ -90,7 +101,6 @@ class LMConfig:
     remat: bool = True
     remat_group: int = 1       # layers rematted together: stash shrinks by
                                # this factor at +((g-1)/g) fwd recompute
-    scan_layers: bool = True
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.bfloat16
 
@@ -100,10 +110,11 @@ class LMConfig:
 
     @property
     def layer_types(self) -> tuple[str, ...]:
-        """Each layer's mixer, "attention" or "mamba", in order."""
+        """Each layer's mixer, "attention", "mamba" or "rwkv", in order."""
         if not self.mixer_period:
-            return (("mamba",) if self.block == "mamba2"
-                    else ("attention",)) * self.num_layers
+            kind = {"attn": "attention", "rwkv6": "rwkv",
+                    "mamba2": "mamba"}[self.block]
+            return (kind,) * self.num_layers
         return tuple("attention" if i % self.mixer_period == self.mixer_offset
                      else "mamba" for i in range(self.num_layers))
 
@@ -132,40 +143,45 @@ class LMConfig:
 
 class DecodeCache(NamedTuple):
     """Per-model decode state: stacked per-layer caches + global position."""
-    layers: Any           # stacked KVCache / RWKVState / MambaState pytree
+    layers: Any           # stacked KVCache / RWKVState / MambaState pytree;
+                          #   a mixed stack: a tuple of them, one per run
     shared: Any           # hybrid only: stacked KVCache per shared-block app
-    length: jax.Array     # [] int32
+    length: jax.Array     # [] int32, or per slot [B]
+
+
+class _Phase(NamedTuple):
+    """What one pass over the stack calls each layer's mixer with.
+    ``kind`` is "train" (``__call__``), "prefill", "decode" or "extend"."""
+    kind: str
+    shard: Shard
+    positions: jax.Array | None = None    # train, prefill: [B, T]
+    max_len: int | None = None            # prefill: the cache's length
+    pos: jax.Array | None = None          # decode, extend: cache.length
+    valid: jax.Array | None = None        # decode, extend: [B], 0 = frozen
 
 
 def _shift(z):
     return jnp.pad(z, ((0, 0), (1, 0), (0, 0)))[:, :-1]
 
 
-def _unrolled_scan(step, x, xs, n):
-    """Python-unrolled lax.scan twin (used by the dry-run cost probes —
-    cost_analysis counts while bodies once, so probes compile unrolled)."""
-    ys = []
-    for i in range(n):
-        sl = jax.tree.map(lambda p: p[i], xs)
-        x, y = step(x, sl)
-        ys.append(y)
-    return x, jax.tree.map(lambda *a: jnp.stack(a), *ys)
-
-
-def _maybe_scan(step, x, xs, use_scan, n):
-    if use_scan:
-        return jax.lax.scan(step, x, xs)
-    return _unrolled_scan(step, x, xs, n)
-
-
-def _layer_loop(step, carry, layers, use_scan, n):
-    """``carry = step(carry, (layer_params, i))`` over the n layers, with
-    no per-layer outputs: state such as the stacked KV cache rides in the
-    carry and is updated in place.  ``i`` is static when unrolled."""
-    carry, _ = _maybe_scan(lambda c, a: (step(c, a), None), carry,
-                           (layers, np.arange(n, dtype=np.int32)), use_scan,
-                           n)
+def _layer_loop(step, carry, layers):
+    """``carry = step(carry, (layer_params, i))`` over the stacked layers,
+    with no per-layer outputs: state such as the stacked KV cache rides in
+    the carry and is updated in place."""
+    n = jax.tree.leaves(layers)[0].shape[0]
+    carry, _ = jax.lax.scan(lambda c, a: (step(c, a), None), carry,
+                            (layers, np.arange(n, dtype=np.int32)))
     return carry
+
+
+def _freeze(valid, new, old):
+    """A state layer's ``new`` state where ``valid > 0``, and ``old``
+    where a slot is frozen (every leaf is ``[B, ...]``)."""
+    if valid is None:
+        return new
+    with jax.named_scope("engine_select"):
+        return jax.tree.map(lambda n, o: jnp.where(
+            (valid > 0).reshape((-1,) + (1,) * (n.ndim - 1)), n, o), new, old)
 
 
 # ---------------------------------------------------------------------------
@@ -178,6 +194,10 @@ class LM:
         c = cfg
         common = dict(param_dtype=c.param_dtype, compute_dtype=c.compute_dtype)
         tnn = c.tnn if c.tnn.enabled else None
+        # Zamba2: the layers between two calls of the shared block
+        self.group = c.hybrid.shared_every if c.hybrid else 0
+        # extend() takes a stack with any state layer token by token
+        self.token_major = set(c.layer_types) != {"attention"}
         if c.block == "attn":
             self.attn = Attention(c.d_model, c.num_heads, c.num_kv_heads,
                                   c.hd, qkv_bias=c.qkv_bias,
@@ -301,174 +321,211 @@ class LM:
                       compute_dtype=c.compute_dtype)(params["lm_head"], x)
             return y / c.logits_scaling if c.logits_scaling != 1.0 else y
 
-    def _moe_apply(self, lp_mlp, y, shard):
-        """Group tokens by batch row (groups shard over `data`)."""
-        c = self.cfg
-        B, T, D = y.shape
-        ym, aux = self.mlp(lp_mlp, y.reshape(B, T, D), shard)
-        return ym.reshape(y.shape), aux
-
-    # -- per-layer functions (train / prefill / decode) ------------------------
-
-    def _attn_layer(self, lp, x, positions, shard):
-        c = self.cfg
-        h = self.attn(lp["attn"], rmsnorm(lp["ln1"], x, c.norm_eps),
-                      positions, shard)
-        x = x + h
-        y = rmsnorm(lp["ln2"], x, c.norm_eps)
-        if c.moe:
-            ym, aux = self._moe_apply(lp["mlp"], y, shard)
-        else:
-            ym, aux = self.mlp(lp["mlp"], y, shard), {}
-        x = shard(x + ym, ("batch", "seq", None))
-        return x, aux
-
-    def _rwkv_layer(self, lp, x, shard, want_state: bool = False):
-        c = self.cfg
-        xn1 = rmsnorm(lp["ln1"], x, c.norm_eps)
-        tm, wkv = self.rwkv.time_mix(lp["rwkv"], xn1, shard)
-        x = x + tm
-        xn2 = rmsnorm(lp["ln2"], x, c.norm_eps)
-        x = x + self.rwkv.channel_mix(lp["rwkv"], xn2, _shift(xn2))
-        if want_state:
-            state = ssm.RWKVState(
-                wkv=wkv,
-                shift_tm=xn1[:, -1].astype(c.compute_dtype),
-                shift_cm=xn2[:, -1].astype(c.compute_dtype))
-            return x, state
-        return x, {}
-
-    def _mamba_layer(self, lp, x, shard, want_state: bool = False):
-        c = self.cfg
-        xn = rmsnorm(lp["ln"], x, c.norm_eps)
-        if want_state:
-            h, state = self.mamba(lp["mamba"], xn, shard, return_state=True)
-            return x + h, state
-        return x + self.mamba(lp["mamba"], xn, shard), {}
-
-    def _shared_block(self, sp, x, positions, shard):
-        c = self.cfg
-        x = x + self.shared_attn(sp["attn"], rmsnorm(sp["ln1"], x, c.norm_eps),
-                                 positions, shard)
-        x = x + self.shared_mlp(sp["mlp"], rmsnorm(sp["ln2"], x, c.norm_eps),
-                                shard)
-        return x
+    # -- layer bodies: one per mixer kind, shared by the four phases -----------
 
     def _residual(self, h):
         m = self.cfg.residual_multiplier
         return h * m if m != 1.0 else h
 
-    def _mixed_layer(self, lp, x, positions, shard, max_len=None):
-        """One layer of a mixed stack: its mixer (attention or Mamba-2, by
-        the key its weights are under), then its own MLP, each added on a
-        scaled residual.  With ``max_len`` (prefill) the second value is
-        the layer's decode state: its KV cache or its Mamba-2 state."""
-        c = self.cfg
-        h = rmsnorm(lp["ln1"], x, c.norm_eps)
-        state = {}
-        if "attn" in lp and max_len is None:
-            m = self.attn(lp["attn"], h, positions, shard)
-        elif "attn" in lp:
-            m, state = self.attn.prefill(lp["attn"], h, positions, max_len,
-                                         shard)
-        elif max_len is None:
-            m = self.mamba(lp["mamba"], h, shard)
+    def _mlp(self, mlp, lp, x, ph):
+        """norm → MLP (SwiGLU, or MoE with its aux) → residual."""
+        y = mlp(lp["mlp"], rmsnorm(lp["ln2"], x, self.cfg.norm_eps),
+                ph.shard)
+        y, aux = y if isinstance(mlp, MoE) else (y, {})
+        x = x + self._residual(y)
+        if ph.kind in ("train", "prefill"):
+            x = ph.shard(x, ("batch", "seq", None))
+        return x, aux
+
+    def _attention(self, lp, x, ph, kv=None, i=None, attn=None, mlp=None):
+        """An attention layer ``{"ln1", "attn", "ln2", "mlp"}``, with the
+        stack's attention and MLP unless ``attn`` / ``mlp`` are given
+        (Zamba2's shared block).  In decode and extend ``kv`` is the run's
+        stacked ``(k, v)`` and ``i`` this layer's index in it.  Returns
+        (x, the layer's KV cache, aux)."""
+        attn, mlp = attn or self.attn, mlp or self.mlp
+        h = rmsnorm(lp["ln1"], x, self.cfg.norm_eps)
+        if ph.kind == "train":
+            m, kv = attn(lp["attn"], h, ph.positions, ph.shard), None
+        elif ph.kind == "prefill":
+            m, kv = attn.prefill(lp["attn"], h, ph.positions, ph.max_len,
+                                 ph.shard)
         else:
-            m, state = self.mamba(lp["mamba"], h, shard, return_state=True)
-        x = x + self._residual(m)
-        y = self.mlp(lp["mlp"], rmsnorm(lp["ln2"], x, c.norm_eps), shard)
-        return shard(x + self._residual(y), ("batch", "seq", None)), state
+            call = attn.decode_step if ph.kind == "decode" else attn.extend
+            m, kv = call(lp["attn"], h, KVCache(*kv, ph.pos), ph.shard,
+                         valid=ph.valid, layer=i)
+        x, aux = self._mlp(mlp, lp, x + self._residual(m), ph)
+        return x, kv, aux
 
-    def _mixed_decode(self, lp, x, state, pos, shard):
-        """One token through one layer of a mixed stack; ``state`` is the
-        layer's KV cache (its length is bookkeeping, ``pos`` is read) or
-        its Mamba-2 state."""
-        c = self.cfg
-        h = rmsnorm(lp["ln1"], x, c.norm_eps)
-        if "attn" in lp:
-            m, kv = self.attn.decode_step(
-                lp["attn"], h, KVCache(state.k, state.v, pos), shard)
-            state = KVCache(kv.k, kv.v, state.length + 1)
+    def _mamba(self, lp, x, ph, state=None):
+        """A Mamba-2 layer: ``{"ln", "mamba"}`` (Zamba2) or, with its own
+        MLP, ``{"ln1", "mamba", "ln2", "mlp"}`` (Granite).  Returns (x, the
+        layer's state, {})."""
+        h = rmsnorm(lp["ln"] if "ln" in lp else lp["ln1"], x,
+                    self.cfg.norm_eps)
+        if ph.kind == "train":
+            m, state = self.mamba(lp["mamba"], h, ph.shard), None
+        elif ph.kind == "prefill":
+            m, state = self.mamba(lp["mamba"], h, ph.shard, return_state=True)
         else:
-            m, state = self.mamba.decode_step(lp["mamba"], h, state)
+            m, new = self.mamba.decode_step(lp["mamba"], h, state)
+            state = _freeze(ph.valid, new, state)
         x = x + self._residual(m)
-        y = self.mlp(lp["mlp"], rmsnorm(lp["ln2"], x, c.norm_eps), shard)
-        return x + self._residual(y), state
+        if "mlp" in lp:
+            x, _ = self._mlp(self.mlp, lp, x, ph)
+        return x, state, {}
 
-    def _scan_runs(self, step, x, layers, *per_run):
-        """``step`` scanned over each run of a mixed stack in order, with
-        each run's entry of ``per_run`` scanned beside its weights;
-        returns x and the runs' stacked outputs."""
-        outs = []
-        for run, *extra in zip(layers, *per_run):
-            n = jax.tree.leaves(run)[0].shape[0]
-            x, y = _maybe_scan(step, x, (run, *extra), self.cfg.scan_layers,
-                               n)
-            outs.append(y)
-        return x, tuple(outs)
+    def _rwkv(self, lp, x, ph, state=None):
+        """An RWKV-6 layer ``{"ln1", "ln2", "rwkv"}``: the time mix, then
+        the channel mix in the MLP's place.  Returns (x, the layer's
+        state, {})."""
+        c, blk = self.cfg, self.rwkv
+        xn1 = rmsnorm(lp["ln1"], x, c.norm_eps)
+        if ph.kind == "decode":
+            tm, wkv, shift_tm = blk.time_mix_step(lp["rwkv"], xn1, state.wkv,
+                                                  state.shift_tm)
+            x = x + tm
+            cm, shift_cm = blk.channel_mix_step(
+                lp["rwkv"], rmsnorm(lp["ln2"], x, c.norm_eps), state.shift_cm)
+            new = ssm.RWKVState(wkv, shift_tm, shift_cm)
+            return x + cm, _freeze(ph.valid, new, state), {}
+        tm, wkv = blk.time_mix(lp["rwkv"], xn1, ph.shard)
+        x = x + tm
+        xn2 = rmsnorm(lp["ln2"], x, c.norm_eps)
+        x = x + blk.channel_mix(lp["rwkv"], xn2, _shift(xn2))
+        if ph.kind == "train":
+            return x, None, {}
+        return x, ssm.RWKVState(wkv, xn1[:, -1].astype(c.compute_dtype),
+                                xn2[:, -1].astype(c.compute_dtype)), {}
 
-    # -- full-sequence forward (training) --------------------------------------
+    # -- the stack walker ---------------------------------------------------------
 
-    def apply_layers(self, layers: dict, x: jax.Array, positions: jax.Array,
-                     shard: Shard = no_shard) -> tuple[jax.Array, dict]:
-        """Run a contiguous slice of the homogeneous layer stack.
-
-        ``layers`` is a stacked ``[L', ...]`` pytree — the full
-        ``params["layers"]`` in :meth:`__call__`, or a stage's slice of it
-        under pipeline parallelism (``repro.distributed.pipeline``).  The
-        scan/remat/remat-group lowering is identical either way, so a
-        partitioned stack computes the same per-layer values as the
-        monolithic forward.  Hybrid (shared-block) stacks interleave
-        non-stack params and stay in :meth:`__call__`; a mixed stack (a
-        tuple of runs) runs run by run.
-        """
+    def _train_run(self, body, lp, x, ph):
+        """A run in training: scanned, each layer (or each group of
+        ``remat_group`` layers) under ``jax.checkpoint`` when ``remat``."""
         c = self.cfg
-        if c.mixer_period and isinstance(layers, tuple):
-            for run in layers:
-                x, _ = self.apply_layers(run, x, positions, shard)
-            return x, {}
-        n = jax.tree.leaves(layers)[0].shape[0]
 
-        def layer_fn(x, lp):
-            if c.mixer_period:
-                return self._mixed_layer(lp, x, positions, shard)
-            if c.block == "attn":
-                return self._attn_layer(lp, x, positions, shard)
-            if c.block == "rwkv6":
-                return self._rwkv_layer(lp, x, shard)
-            return self._mamba_layer(lp, x, shard)
+        def layer_fn(x, p):
+            x, _, aux = body(p, x, ph)
+            return x, aux
 
         if c.remat:
             layer_fn = jax.checkpoint(
                 layer_fn, policy=jax.checkpoint_policies.nothing_saveable)
+        n = jax.tree.leaves(lp)[0].shape[0]
+        g = max(1, c.remat_group)
+        if g > 1 and n % g == 0:
+            def group_fn(x, gp):
+                aux = None
+                for li in range(g):
+                    x, aux = layer_fn(x, jax.tree.map(lambda p: p[li], gp))
+                return x, aux
+            if c.remat:
+                group_fn = jax.checkpoint(
+                    group_fn, policy=jax.checkpoint_policies.nothing_saveable)
+            grouped = jax.tree.map(
+                lambda p: p.reshape((n // g, g) + p.shape[1:]), lp)
+            return jax.lax.scan(group_fn, x, grouped)
+        return jax.lax.scan(layer_fn, x, lp)
 
-        if c.scan_layers:
-            g = max(1, c.remat_group)
-            if g > 1 and n % g == 0:
-                def group_fn(x, gp):
-                    aux = None
-                    for li in range(g):
-                        lp = jax.tree.map(lambda p: p[li], gp)
-                        x, aux = layer_fn(x, lp)
-                    return x, aux
-                if c.remat:
-                    group_fn = jax.checkpoint(
-                        group_fn,
-                        policy=jax.checkpoint_policies.nothing_saveable)
-                grouped = jax.tree.map(
-                    lambda p: p.reshape((n // g, g) + p.shape[1:]),
-                    layers)
-                x, aux = jax.lax.scan(group_fn, x, grouped)
-            else:
-                x, aux = jax.lax.scan(layer_fn, x, layers)
+    def _run(self, lp, x, ph, states=None):
+        """One run of same-kind layers: stacked ``[n, ...]`` params ``lp``
+        and, in decode and extend, their stacked states.  Returns (x, the
+        run's stacked states, aux)."""
+        body = (self._attention if "attn" in lp else
+                self._mamba if "mamba" in lp else self._rwkv)
+        if ph.kind == "train":
+            x, aux = self._train_run(body, lp, x, ph)
+            return x, None, aux
+        if ph.kind == "prefill":
+            def step(x, p):
+                x, state, _ = body(p, x, ph)
+                return x, state
+            x, states = jax.lax.scan(step, x, lp)
+            return x, states, {}
+        if "attn" in lp:
+            def step(carry, scan_in):
+                x, k, v = carry
+                p, i = scan_in
+                x, kv, _ = body(p, x, ph, (k, v), i)
+                return x, kv.k, kv.v
+            x, ks, vs = _layer_loop(step, (x, states.k, states.v), lp)
+            # Per-layer lengths are bookkeeping only (decode and extend
+            # read the global cache.length): advance by the tokens fed.
+            return x, KVCache(ks, vs, states.length + x.shape[1]), {}
+
+        def step(x, scan_in):
+            p, state = scan_in
+            x, state, _ = body(p, x, ph, state)
+            return x, state
+        x, states = jax.lax.scan(step, x, (lp, states))
+        return x, states, {}
+
+    def _runs(self, tree):
+        """``params["layers"]`` or ``DecodeCache.layers`` as the walker's
+        runs: a mixed stack's tuple as it is, a homogeneous stack as one
+        run, a Zamba2 stack cut into its groups.  (A run's own states may
+        be a NamedTuple, so the test is for a plain tuple.)"""
+        if type(tree) is tuple:
+            return tree
+        n, g = jax.tree.leaves(tree)[0].shape[0], self.group
+        if not g or g == n:
+            return (tree,)
+        return tuple(jax.tree.map(lambda a: a[i:i + g], tree)
+                     for i in range(0, n, g))
+
+    def _walk(self, params, x, ph, cache: DecodeCache | None = None):
+        """The whole stack in phase ``ph``: each run of ``params["layers"]``
+        in order, with Zamba2's shared block after each group.  ``cache``
+        (decode, extend) holds the states going in.  Returns (x, the
+        layers' states and the shared block's, in ``DecodeCache``'s
+        layouts, and the training aux)."""
+        runs = self._runs(params["layers"])
+        states = ((None,) * len(runs) if cache is None
+                  else self._runs(cache.layers))
+        shared = None if cache is None else cache.shared
+        shared_kv = None if shared is None else (shared.k, shared.v)
+        outs, kvs, aux = [], [], {}
+        for gi, (lp, st) in enumerate(zip(runs, states)):
+            x, st, run_aux = self._run(lp, x, ph, st)
+            outs.append(st)
+            aux.update(run_aux)
+            if self.group:
+                x, kv, _ = self._attention(params["shared"], x, ph, shared_kv,
+                                           gi, self.shared_attn,
+                                           self.shared_mlp)
+                kvs.append(kv)
+                shared_kv = None if kv is None else (kv.k, kv.v)
+        if ph.kind == "train":
+            return x, None, None, aux
+        if type(params["layers"]) is tuple:
+            layers = tuple(outs)
+        elif len(outs) == 1:
+            layers = outs[0]
         else:
-            auxes = []
-            for li in range(n):
-                lp = jax.tree.map(lambda p: p[li], layers)
-                x, a = layer_fn(x, lp)
-                auxes.append(a)
-            aux = (jax.tree.map(lambda *a: jnp.stack(a), *auxes)
-                   if auxes and auxes[0] else {})
+            layers = jax.tree.map(lambda *s: jnp.concatenate(s), *outs)
+        if shared is not None:
+            shared = KVCache(*shared_kv, shared.length + x.shape[1])
+        elif kvs:
+            shared = jax.tree.map(lambda *s: jnp.stack(s), *kvs)
+        return x, layers, shared, aux
+
+    # -- full-sequence forward (training) --------------------------------------
+
+    def apply_layers(self, layers, x: jax.Array, positions: jax.Array,
+                     shard: Shard = no_shard) -> tuple[jax.Array, dict]:
+        """Run a contiguous slice of the layer stack in training.
+
+        ``layers`` is a stacked ``[L', ...]`` pytree (a mixed stack: a
+        tuple of runs) — the full ``params["layers"]``, or a stage's slice
+        of it under pipeline parallelism (``repro.distributed.pipeline``).
+        The scan/remat/remat-group lowering is identical either way, so a
+        partitioned stack computes the same per-layer values as the
+        monolithic forward.  Hybrid (shared-block) stacks need
+        ``params["shared"]`` and run through :meth:`__call__`.
+        """
+        x, _, _, aux = self._walk({"layers": layers}, x,
+                                  _Phase("train", shard, positions))
         return x, aux
 
     def __call__(self, params: dict, inputs: jax.Array,
@@ -478,26 +535,7 @@ class LM:
         B, T = inputs.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
         x = self._embed(params, inputs, shard)
-
-        if c.hybrid:
-            def layer_fn(x, lp):
-                return self._mamba_layer(lp, x, shard)
-            if c.remat:
-                layer_fn = jax.checkpoint(
-                    layer_fn, policy=jax.checkpoint_policies.nothing_saveable)
-            g = c.hybrid.shared_every
-            n_groups = c.num_layers // g
-            grouped = jax.tree.map(
-                lambda p: p.reshape((n_groups, g) + p.shape[1:]),
-                params["layers"])
-            for gi in range(n_groups):
-                gp = jax.tree.map(lambda p: p[gi], grouped)
-                x, _ = jax.lax.scan(layer_fn, x, gp)
-                x = self._shared_block(params["shared"], x, positions, shard)
-            aux = {}
-        else:
-            x, aux = self.apply_layers(params["layers"], x, positions, shard)
-
+        x, _, _, aux = self._walk(params, x, _Phase("train", shard, positions))
         x = rmsnorm(params["ln_f"], x, c.norm_eps)
         logits = self._logits(params, x)
         return shard(logits, ("batch", "seq", "vocab")), aux
@@ -545,43 +583,25 @@ class LM:
 
     def init_cache(self, batch: int, max_len: int) -> DecodeCache:
         c = self.cfg
-        L = c.num_layers
 
-        def stack(state, n=L):
-            return jax.tree.map(
-                lambda s: jnp.zeros((n,) + s.shape, s.dtype), state)
+        def kv(n):
+            shape = (n, batch, max_len, c.num_kv_heads, c.hd)
+            return KVCache(k=jnp.zeros(shape, c.compute_dtype),
+                           v=jnp.zeros(shape, c.compute_dtype),
+                           length=jnp.zeros((n,), jnp.int32))
 
-        shared = None
-        if c.mixer_period:
-            def run_cache(kind, n):
-                if kind == "attention":
-                    shape = (n, batch, max_len, c.num_kv_heads, c.hd)
-                    return KVCache(k=jnp.zeros(shape, c.compute_dtype),
-                                   v=jnp.zeros(shape, c.compute_dtype),
-                                   length=jnp.zeros((n,), jnp.int32))
-                return stack(self.mamba.init_state(batch), n)
-            layers = tuple(run_cache(kind, n) for kind, n in c.runs)
-        elif c.block == "attn":
-            layers = KVCache(
-                k=jnp.zeros((L, batch, max_len, c.num_kv_heads, c.hd),
-                            c.compute_dtype),
-                v=jnp.zeros((L, batch, max_len, c.num_kv_heads, c.hd),
-                            c.compute_dtype),
-                length=jnp.zeros((L,), jnp.int32))
-        elif c.block == "rwkv6":
-            layers = stack(self.rwkv.init_state(batch))
-        else:
-            layers = stack(self.mamba.init_state(batch))
-            if c.hybrid:
-                n_groups = c.num_layers // c.hybrid.shared_every
-                shared = KVCache(
-                    k=jnp.zeros((n_groups, batch, max_len, c.num_kv_heads,
-                                 c.hd), c.compute_dtype),
-                    v=jnp.zeros((n_groups, batch, max_len, c.num_kv_heads,
-                                 c.hd), c.compute_dtype),
-                    length=jnp.zeros((n_groups,), jnp.int32))
-        return DecodeCache(layers=layers, shared=shared,
-                           length=jnp.array(0, jnp.int32))
+        def run(kind, n):
+            if kind == "attention":
+                return kv(n)
+            block = self.mamba if kind == "mamba" else self.rwkv
+            return jax.tree.map(lambda s: jnp.zeros((n,) + s.shape, s.dtype),
+                                block.init_state(batch))
+
+        layers = tuple(run(kind, n) for kind, n in c.runs)
+        return DecodeCache(
+            layers=layers if c.mixer_period else layers[0],
+            shared=kv(c.num_layers // self.group) if self.group else None,
+            length=jnp.array(0, jnp.int32))
 
     # -- decode -------------------------------------------------------------------
 
@@ -590,107 +610,23 @@ class LM:
                     ) -> tuple[jax.Array, DecodeCache]:
         """token: [B] ids (or [B, D] embeds) -> (logits [B, V], new cache).
 
-        ``valid`` ([B] int32 of 0 or 1, None = every slot; attention blocks
-        only) freezes the slots with 0 inside each layer's KV write, as
-        :meth:`Attention.decode_step` says: their k/v and length come back
-        as they went in.  An attention stack carries its stacked K/V
-        through the layer loop and writes only the new rows, so a caller
-        that donates the cache has it updated in place."""
+        ``valid`` ([B] int32 of 0 or 1, None = every slot) freezes the
+        slots with 0: their state, k/v and length come back as they went
+        in.  An attention layer freezes inside its KV write, as
+        :meth:`Attention.decode_step` says; a state layer keeps a frozen
+        slot's state with a ``where`` over that layer's ``[B, ...]``
+        state.  Attention runs carry their stacked K/V through the layer
+        loop and write only the new rows, so a caller that donates the
+        cache has it updated in place."""
         c = self.cfg
-        if valid is not None and c.block != "attn":
-            raise ValueError("decode_step(valid=...) requires an "
-                             "attention-block model")
-        B = token.shape[0]
         inputs = token[:, None] if token.ndim == 1 else token[:, None, :]
         x = self._embed(params, inputs, shard)
-        pos = cache.length
-        new_shared = None
-
-        if c.mixer_period:
-            def step(x, scan_in):
-                lp, st = scan_in
-                return self._mixed_decode(lp, x, st, pos, shard)
-            x, new_layers = self._scan_runs(step, x, params["layers"],
-                                            cache.layers)
-        elif c.block == "attn":
-            def step(carry, scan_in):
-                x, k, v = carry
-                lp, i = scan_in
-                h, new_kv = self.attn.decode_step(
-                    lp["attn"], rmsnorm(lp["ln1"], x, c.norm_eps),
-                    KVCache(k, v, pos), shard, valid=valid, layer=i)
-                x = x + h
-                y = rmsnorm(lp["ln2"], x, c.norm_eps)
-                if c.moe:
-                    ym, _ = self._moe_apply(lp["mlp"], y, shard)
-                else:
-                    ym = self.mlp(lp["mlp"], y, shard)
-                return x + ym, new_kv.k, new_kv.v
-            x, ks, vs = _layer_loop(
-                step, (x, cache.layers.k, cache.layers.v), params["layers"],
-                c.scan_layers, c.num_layers)
-            new_layers = KVCache(ks, vs, cache.layers.length + 1)
-        elif c.block == "rwkv6":
-            def step(x, scan_in):
-                lp, st = scan_in
-                tm, new_wkv, new_sh_tm = self.rwkv.time_mix_step(
-                    lp["rwkv"], rmsnorm(lp["ln1"], x, c.norm_eps),
-                    st.wkv, st.shift_tm)
-                x = x + tm
-                cm, new_sh_cm = self.rwkv.channel_mix_step(
-                    lp["rwkv"], rmsnorm(lp["ln2"], x, c.norm_eps), st.shift_cm)
-                return x + cm, ssm.RWKVState(new_wkv, new_sh_tm, new_sh_cm)
-            if c.scan_layers:
-                x, new_layers = jax.lax.scan(step, x, (params["layers"],
-                                                       cache.layers))
-            else:
-                x, new_layers = _unrolled_scan(step, x, (params["layers"],
-                                                         cache.layers),
-                                               c.num_layers)
-        else:
-            def step(x, scan_in):
-                lp, st = scan_in
-                h, new_st = self.mamba.decode_step(
-                    lp["mamba"], rmsnorm(lp["ln"], x, c.norm_eps), st)
-                return x + h, new_st
-
-            if c.hybrid:
-                g = c.hybrid.shared_every
-                n_groups = c.num_layers // g
-                grouped = jax.tree.map(
-                    lambda p: p.reshape((n_groups, g) + p.shape[1:]),
-                    params["layers"])
-                new_layer_states, new_shared_list = [], []
-                for gi in range(n_groups):
-                    gp = jax.tree.map(lambda p: p[gi], grouped)
-                    gs = jax.tree.map(lambda s: s[gi * g:(gi + 1) * g],
-                                      cache.layers)
-                    x, ns = jax.lax.scan(step, x, (gp, gs))
-                    new_layer_states.append(ns)
-                    kv = jax.tree.map(lambda s: s[gi], cache.shared)
-                    lkv = KVCache(kv.k, kv.v, pos)
-                    h, new_kv = self.shared_attn.decode_step(
-                        params["shared"]["attn"],
-                        rmsnorm(params["shared"]["ln1"], x, c.norm_eps),
-                        lkv, shard)
-                    x = x + h
-                    x = x + self.shared_mlp(
-                        params["shared"]["mlp"],
-                        rmsnorm(params["shared"]["ln2"], x, c.norm_eps), shard)
-                    new_shared_list.append((new_kv.k, new_kv.v))
-                new_layers = jax.tree.map(
-                    lambda *s: jnp.concatenate(s), *new_layer_states)
-                new_shared = KVCache(
-                    k=jnp.stack([k for k, _ in new_shared_list]),
-                    v=jnp.stack([v for _, v in new_shared_list]),
-                    length=cache.shared.length + 1)
-            else:
-                x, new_layers = jax.lax.scan(step, x, (params["layers"],
-                                                       cache.layers))
-
+        x, layers, shared, _ = self._walk(
+            params, x, _Phase("decode", shard, pos=cache.length, valid=valid),
+            cache)
         x = rmsnorm(params["ln_f"], x, c.norm_eps)
         logits = self._logits(params, x)[:, 0]
-        return logits, DecodeCache(layers=new_layers, shared=new_shared,
+        return logits, DecodeCache(layers=layers, shared=shared,
                                    length=cache.length + (1 if valid is None
                                                           else valid))
 
@@ -700,46 +636,39 @@ class LM:
                shard: Shard = no_shard, valid: jax.Array | None = None
                ) -> tuple[jax.Array, DecodeCache]:
         """Ingest a ``[B, C]`` token chunk at each slot's current cache
-        depth — the serving engine's chunked-prefill tick (attention
-        blocks only; SSM blocks go through the engine's sequential
-        decode_step fallback).
+        depth — the serving engine's chunked-prefill tick.
 
         ``cache.length`` may be per-slot ([B]); ``valid`` ([B] int32,
-        None = all C) bounds how many chunk tokens are real per slot (see
+        None = all C) bounds how many chunk tokens are real per slot, and
+        a slot with 0 comes back as it went in (see
         :meth:`Attention.extend` for the masked-write contract).  Returns
         logits for every chunk position ([B, C, V] — the engine reads row
         ``valid-1`` of slots whose prompt just completed) plus the
-        advanced cache."""
+        advanced cache.  An attention stack takes the chunk layer by
+        layer; a stack with any state layer takes it token by token, a
+        ``lax.scan`` of :meth:`decode_step` over the chunk's columns in
+        which column ``i`` of slot ``b`` is real while ``i < valid[b]``."""
         c = self.cfg
-        assert c.block == "attn" and not c.hybrid, (
-            "extend() requires an attention-block model")
-        B, C = tokens.shape[:2]
+        C = tokens.shape[1]
+        if self.token_major:
+            def column(cache, scan_in):
+                col, i = scan_in
+                live = None if valid is None else (i < valid).astype(jnp.int32)
+                logits, cache = self.decode_step(params, col, cache, shard,
+                                                 valid=live)
+                return cache, logits
+
+            cache, logits = jax.lax.scan(
+                column, cache, (jnp.moveaxis(tokens, 1, 0), jnp.arange(C)))
+            return jnp.moveaxis(logits, 0, 1), cache
         x = self._embed(params, tokens, shard)
-        pos = cache.length
-
-        def step(carry, scan_in):
-            x, k, v = carry
-            lp, i = scan_in
-            h, new_kv = self.attn.extend(
-                lp["attn"], rmsnorm(lp["ln1"], x, c.norm_eps),
-                KVCache(k, v, pos), shard, valid=valid, layer=i)
-            x = x + h
-            y = rmsnorm(lp["ln2"], x, c.norm_eps)
-            if c.moe:
-                ym, _ = self._moe_apply(lp["mlp"], y, shard)
-            else:
-                ym = self.mlp(lp["mlp"], y, shard)
-            return x + ym, new_kv.k, new_kv.v
-
-        x, ks, vs = _layer_loop(step, (x, cache.layers.k, cache.layers.v),
-                                params["layers"], c.scan_layers, c.num_layers)
-        # Per-layer lengths are bookkeeping only (decode/extend read the
-        # global cache.length); advance by the chunk width.
-        new_layers = KVCache(ks, vs, cache.layers.length + C)
+        x, layers, shared, _ = self._walk(
+            params, x, _Phase("extend", shard, pos=cache.length, valid=valid),
+            cache)
         adv = C if valid is None else valid
         x = rmsnorm(params["ln_f"], x, c.norm_eps)
         logits = self._logits(params, x)                      # [B, C, V]
-        return logits, DecodeCache(layers=new_layers, shared=None,
+        return logits, DecodeCache(layers=layers, shared=shared,
                                    length=cache.length + adv)
 
     # -- prefill --------------------------------------------------------------
@@ -752,66 +681,9 @@ class LM:
         B, T = inputs.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
         x = self._embed(params, inputs, shard)
-        new_shared = None
-
-        if c.mixer_period:
-            def step(x, scan_in):
-                return self._mixed_layer(scan_in[0], x, positions, shard,
-                                         max_len=max_len)
-            x, new_layers = self._scan_runs(step, x, params["layers"])
-        elif c.block == "attn":
-            def step(x, lp):
-                h, kv = self.attn.prefill(
-                    lp["attn"], rmsnorm(lp["ln1"], x, c.norm_eps), positions,
-                    max_len, shard)
-                x = x + h
-                y = rmsnorm(lp["ln2"], x, c.norm_eps)
-                if c.moe:
-                    ym, _ = self._moe_apply(lp["mlp"], y, shard)
-                else:
-                    ym = self.mlp(lp["mlp"], y, shard)
-                return x + ym, (kv.k, kv.v)
-            x, (ks, vs) = _maybe_scan(step, x, params["layers"],
-                                      c.scan_layers, c.num_layers)
-            new_layers = KVCache(ks, vs, jnp.full((c.num_layers,), T, jnp.int32))
-        elif c.block == "rwkv6":
-            def step(x, lp):
-                return self._rwkv_layer(lp, x, shard, want_state=True)
-            x, new_layers = _maybe_scan(step, x, params["layers"],
-                                        c.scan_layers, c.num_layers)
-        else:
-            def step(x, lp):
-                return self._mamba_layer(lp, x, shard, want_state=True)
-            if c.hybrid:
-                g = c.hybrid.shared_every
-                n_groups = c.num_layers // g
-                grouped = jax.tree.map(
-                    lambda p: p.reshape((n_groups, g) + p.shape[1:]),
-                    params["layers"])
-                states, shared_kvs = [], []
-                for gi in range(n_groups):
-                    gp = jax.tree.map(lambda p: p[gi], grouped)
-                    x, st = jax.lax.scan(step, x, gp)
-                    states.append(st)
-                    sp = params["shared"]
-                    h, kv = self.shared_attn.prefill(
-                        sp["attn"], rmsnorm(sp["ln1"], x, c.norm_eps),
-                        positions, max_len, shard)
-                    x = x + h
-                    x = x + self.shared_mlp(
-                        sp["mlp"], rmsnorm(sp["ln2"], x, c.norm_eps), shard)
-                    shared_kvs.append((kv.k, kv.v))
-                new_layers = jax.tree.map(lambda *s: jnp.concatenate(s),
-                                          *states)
-                new_shared = KVCache(
-                    k=jnp.stack([k for k, _ in shared_kvs]),
-                    v=jnp.stack([v for _, v in shared_kvs]),
-                    length=jnp.full((n_groups,), T, jnp.int32))
-            else:
-                x, new_layers = _maybe_scan(step, x, params["layers"],
-                                            c.scan_layers, c.num_layers)
-
+        x, layers, shared, _ = self._walk(
+            params, x, _Phase("prefill", shard, positions, max_len))
         x = rmsnorm(params["ln_f"], x, c.norm_eps)
         logits = self._logits(params, x[:, -1:])[:, 0]
-        return logits, DecodeCache(layers=new_layers, shared=new_shared,
+        return logits, DecodeCache(layers=layers, shared=shared,
                                    length=jnp.array(T, jnp.int32))

@@ -30,21 +30,21 @@ arrays are the authoritative slot state; the device cache's ``length``
 is overwritten from them before every call.
 
 Every tick program takes the device state donated and returns its
-successor, which the engine keeps in place of what it passed.  With a
-native ``extend`` and a bf16 cache, the model writes the new rows in
-place into the donated cache (the stacked K/V ride the layer loop's
-carry; only each call's rows per slot and layer are written), and it
-freezes inactive slots inside that write, so a garbage write from a
-padded lane can never corrupt a live slot's state: ``extend`` and
-``decode_step`` take ``valid`` and put back a frozen slot's rows where
-they would write, never selecting over the whole cache.  Models without
-a native ``extend`` (SSM/hybrid blocks) prefill through a sequential
-fallback, a ``lax.scan`` of ``decode_step`` over chunk columns, and they
-and the quantized cache freeze by a per-leaf batch-axis select over the
-whole state (SSM states have no rows to mask, and the quantized path
-must keep padding out of its monotone amax); so does the slot-zeroing at
-admission.  The counters ``serve.kv_inplace`` and ``serve.kv_whole``
-count the calls of each kind.
+successor, which the engine keeps in place of what it passed.  The
+engine goes through one path for every model: ``extend`` and
+``decode_step`` take ``valid`` and freeze inactive slots themselves, so a
+garbage write from a padded lane can never corrupt a live slot's state.
+How a layer freezes is the model's business (``LM.decode_step``): an
+attention layer puts back a frozen slot's rows where it would write,
+inside its KV write into the donated cache, and a state layer (RWKV,
+Mamba-2) keeps a frozen slot's state with a ``where`` over that layer's
+state; no call selects over the whole cache.  The quantized cache
+rebuilds and requantizes its K/V around each call, and keeps padding out
+of its monotone amax with a select (``requant``).  The slot-zeroing at
+admission is the one whole-state select left (``_select``): recurrent
+states must start from zero.  The counter ``serve.kv_inplace`` counts the
+bf16 extend and decode calls; ``serve.kv_whole`` counts the quantized
+calls and the slot-zeroing.
 """
 
 from __future__ import annotations
@@ -116,7 +116,6 @@ class ServeEngine:
         cfg = getattr(model, "cfg", None)
         attn_only = cfg is None or (getattr(cfg, "block", "attn") == "attn"
                                     and not getattr(cfg, "hybrid", None))
-        self._native_extend = attn_only and hasattr(model, "extend")
         if kv_policy is not None and not attn_only:
             raise ValueError("quantized KV requires an attention-only model")
 
@@ -180,10 +179,9 @@ class ServeEngine:
 
     def _select(self, active, new, old):
         """Per-leaf batch-axis select over the whole state: inactive slots
-        keep their old state.  It serves the sequential fallback and the
-        slot-zeroing at admission; the quantized cache selects in
-        ``requant``, and the native bf16 path writes its rows in place
-        into the donated cache and freezes inside that write instead.
+        keep their old state.  Only the slot-zeroing at admission uses it;
+        the tick programs freeze inside the model and the quantized cache
+        selects in ``requant``.
         Axis rule: every stacked per-layer buffer in
         this repo is >= 3-D with batch on axis 1 ([L, B, ...]), per-slot
         vectors are 1-/2-D with batch on axis 0 — checked in that order,
@@ -207,49 +205,17 @@ class ServeEngine:
     def _build_step_fns(self):
         model, shard, policy = self.model, self.shard, self.kv_policy
 
-        if self._native_extend:
-            def extend_raw(params, toks, cache, valid):
-                return model.extend(params, toks, cache, shard, valid=valid)
-        else:
-            def extend_raw(params, toks, cache, valid):
-                # Sequential fallback: scan decode_step over chunk
-                # columns; a slot past its valid count is frozen.
-                C = toks.shape[1]
-
-                def step(cache, col_i):
-                    col, i = col_i
-                    logits, new = model.decode_step(params, col, cache,
-                                                    shard)
-                    active = i < valid
-                    return self._select(active, new, cache), logits
-
-                cache, logits = jax.lax.scan(
-                    step, cache, (toks.T, jnp.arange(C)))
-                return jnp.transpose(logits, (1, 0, 2)), cache
-
         if policy is None:
-            if self._native_extend:
-                # Inactive slots have valid == 0: the model freezes them
-                # inside its KV write, and their lengths advance by 0.
-                def extend_fn(params, toks, cache, lengths, valid, active):
-                    cache = cache._replace(length=lengths)
-                    return extend_raw(params, toks, cache, valid)
+            # Inactive slots have valid == 0: the model freezes them, and
+            # their lengths advance by 0.
+            def extend_fn(params, toks, cache, lengths, valid, active):
+                cache = cache._replace(length=lengths)
+                return model.extend(params, toks, cache, shard, valid=valid)
 
-                def decode_fn(params, tok, cache, lengths, active):
-                    cache = cache._replace(length=lengths)
-                    return model.decode_step(params, tok, cache, shard,
-                                             valid=active.astype(jnp.int32))
-            else:
-                def extend_fn(params, toks, cache, lengths, valid, active):
-                    cache = cache._replace(length=lengths)
-                    logits, new = extend_raw(params, toks, cache, valid)
-                    return logits, self._select(active, new, cache)
-
-                def decode_fn(params, tok, cache, lengths, active):
-                    cache = cache._replace(length=lengths)
-                    logits, new = model.decode_step(params, tok, cache,
-                                                    shard)
-                    return logits, self._select(active, new, cache)
+            def decode_fn(params, tok, cache, lengths, active):
+                cache = cache._replace(length=lengths)
+                return model.decode_step(params, tok, cache, shard,
+                                         valid=active.astype(jnp.int32))
 
             def zero_fn(cache, admit):
                 zeros = jax.tree.map(jnp.zeros_like, cache)
@@ -273,7 +239,8 @@ class ServeEngine:
 
             def extend_fn(params, toks, qkv, lengths, valid, active):
                 cache, k, v = rebuild(qkv, lengths)
-                logits, new = extend_raw(params, toks, cache, valid)
+                logits, new = model.extend(params, toks, cache, shard,
+                                           valid=valid)
                 return logits, requant(new, k, v, qkv, active)
 
             def decode_fn(params, tok, qkv, lengths, active):
@@ -289,8 +256,8 @@ class ServeEngine:
         self._decode_fn = jax.jit(decode_fn, donate_argnums=2)
         self._zero_fn = (jax.jit(zero_fn, donate_argnums=0)
                          if zero_fn is not None else None)
-        self._kv_counter = ("serve.kv_inplace" if self._native_extend
-                            and policy is None else "serve.kv_whole")
+        self._kv_counter = ("serve.kv_inplace" if policy is None
+                            else "serve.kv_whole")
 
     def _state(self):
         return self.cache if self.kv_policy is None else self.qkv

@@ -451,6 +451,69 @@ def test_native_path_freezes_inactive_slots_in_the_write(tiny_lm):
                                   np.asarray(lengths + active))
 
 
+def _slot(cache, b):
+    """Slot ``b`` of a decode cache: stacked per-layer leaves keep their
+    layer axis and take batch row ``b`` on axis 1; the per-slot length
+    takes row ``b``; per-layer length bookkeeping is dropped."""
+    def rows(a):
+        return a[:, b] if a.ndim >= 3 else None
+    return (jax.tree.map(rows, (cache.layers, cache.shared)),
+            cache.length[b])
+
+
+@pytest.mark.parametrize("arch", ["rwkv6_7b", "zamba2_7b",
+                                  "granite_4_0_h_micro"])
+def test_state_stack_extend_is_successive_decode_steps(arch):
+    """On a stack with state layers (RWKV-6, Zamba2's Mamba-2 groups with
+    the shared attention block, Granite's Mamba-2 and attention runs),
+    ``LM.extend`` over a chunk with per-slot ``valid`` equals, for each
+    slot ``b``, ``valid[b]`` successive ``decode_step`` calls: the same
+    logits rows and the same state; a slot with ``valid == 0`` comes back
+    bit-identical.  And ``decode_step(valid=)`` leaves a frozen slot's
+    whole state bit-identical while the live slots step as with
+    ``valid=None``."""
+    from repro.configs import base as cfgbase
+    from repro.launch import steps
+    model, cfg = steps.build_model(cfgbase.get(arch), smoke=True)
+    params = model.init(jax.random.key(0))
+    B, C = 3, 4
+    k1, k2 = jax.random.split(jax.random.key(9))
+    ext, dec = jax.jit(model.extend), jax.jit(model.decode_step)
+    cache = model.init_cache(B, 16)
+    cache = cache._replace(length=jnp.zeros(B, jnp.int32))
+    # a live state at a different depth in each slot
+    _, cache = ext(params, jax.random.randint(k1, (B, 3), 0, cfg.vocab),
+                   cache, valid=jnp.array([3, 2, 1], jnp.int32))
+    toks = jax.random.randint(k2, (B, C), 0, cfg.vocab)
+    valid = np.array([C, 0, 2], np.int32)
+    logits, got = ext(params, toks, cache, valid=jnp.asarray(valid))
+    assert logits.shape == (B, C, cfg.vocab)
+
+    ref, rows = cache, []
+    for i in range(C):
+        lg, ref = dec(params, toks[:, i], ref)
+        rows.append(lg)
+        for b in np.nonzero(valid == i + 1)[0]:
+            jax.tree.map(np.testing.assert_array_equal, _slot(got, b),
+                         _slot(ref, b))
+            np.testing.assert_array_equal(
+                np.asarray(logits[b, :i + 1]),
+                np.asarray(jnp.stack(rows, 1)[b]))
+    jax.tree.map(np.testing.assert_array_equal, _slot(got, 1),
+                 _slot(cache, 1))
+
+    live = jnp.array([1, 0, 1], jnp.int32)
+    lg, frozen = dec(params, toks[:, 0], cache, valid=live)
+    want_lg, want = dec(params, toks[:, 0], cache)
+    jax.tree.map(np.testing.assert_array_equal, _slot(frozen, 1),
+                 _slot(cache, 1))
+    for b in (0, 2):
+        jax.tree.map(np.testing.assert_array_equal, _slot(frozen, b),
+                     _slot(want, b))
+        np.testing.assert_array_equal(np.asarray(lg[b]),
+                                      np.asarray(want_lg[b]))
+
+
 def test_donated_engine_serves_soundly(tiny_lm):
     """The tick programs donate the state they are given.  After
     ``warmup()`` an engine admits, prefills, decodes and re-admits into a

@@ -582,16 +582,22 @@ def _largest_in_scope(compiled, scope) -> int:
 @pytest.mark.parametrize("arch,whole", [("internlm2_1_8b", False),
                                         ("rwkv6_7b", True)])
 def test_engine_select_spans_written_rows_or_whole_state(arch, whole):
-    """On the native bf16 path the ``engine_select`` scope holds only the
-    chunk-sized freeze of the rows written, smaller than one layer's
-    ``[B, cache_len, KV, hd]`` cache; the SSM fallback still selects over
-    its whole state."""
+    """The tick programs' ``engine_select`` scope holds only what one
+    layer freezes.  On an attention stack that is the chunk-sized rows
+    written, smaller than one layer's ``[B, cache_len, KV, hd]`` cache; on
+    a state stack (RWKV-6) it is one layer's ``[B, ...]`` state, never the
+    whole stacked state.  Only the slot-zeroing at admission selects over
+    the whole state."""
     eng, programs = _engine_programs(arch)
+    state = eng._state()
     if whole:
-        leaf = max(a.size for a in jax.tree.leaves(eng._state()))
+        layer = max(a[0].size for a in jax.tree.leaves(state.layers))
     else:
-        leaf = eng._state().layers.k[0].size
+        layer = state.layers.k[0].size
     for compiled in programs:
         largest = _largest_in_scope(compiled, "engine_select")
         assert largest > 0
-        assert (largest >= leaf) if whole else (largest < leaf)
+        assert (largest == layer) if whole else (largest < layer)
+    zero = eng._zero_fn.lower(state, jnp.zeros(eng.batch, bool)).compile()
+    assert (_largest_in_scope(zero, "engine_select")
+            == max(a.size for a in jax.tree.leaves(state)))
